@@ -567,38 +567,6 @@ def _serving_prefix_router_rows():
     ]
 
 
-def _sharded_decode_rows():
-    """Tensor-parallel paged decode on a 4-way simulated model mesh.
-
-    Forks `tests/_sharded_parity_child.py bench` (the in-process device
-    count is pinned to 1; XLA's forced-device-count flag only works
-    before jax initializes): the child re-asserts *bitwise* parity of the
-    ``raceit_gqa_tp`` backend against the single-device
-    ``raceit_gqa_paged`` partner on the same page pool, then reports
-    interleaved min-of-N us/call. The wall time includes the 4-way
-    shard_map + probe/pmax/exact collective protocol, so the row tracks
-    TP dispatch overhead on simulated devices — not real scaling (that
-    needs real chips), but a trend wire for the sharded code path. A
-    parity break fails the bench outright, like the noise-sweep gates.
-    """
-    import subprocess
-    child = (Path(__file__).resolve().parent.parent / "tests" /
-             "_sharded_parity_child.py")
-    env = {"PYTHONPATH": str(Path(__file__).resolve().parent.parent / "src"),
-           "PATH": "/usr/bin:/bin", "HOME": "/tmp"}
-    out = subprocess.run([sys.executable, str(child), "bench"], env=env,
-                         capture_output=True, text=True, timeout=600)
-    if "BENCH_OK" not in out.stdout:
-        raise SystemExit(f"sharded decode bench failed:\n{out.stdout}\n"
-                         f"{out.stderr[-3000:]}")
-    vals = {l.split()[0]: float(l.split()[1])
-            for l in out.stdout.splitlines()
-            if l.startswith(("TP_DECODE_US", "REF_DECODE_US"))}
-    return [("kernel/attention_decode_tp_gqa_model4_ps64",
-             vals["TP_DECODE_US"],
-             f"bitwise_vs_1dev_{vals['REF_DECODE_US'] / vals['TP_DECODE_US']:.2f}x")]
-
-
 def _noise_sweep_rows():
     """Fast accuracy-under-device-noise smoke (the CI noise gate).
 
@@ -648,7 +616,6 @@ def run() -> list[tuple]:
     rows.extend(_decode_gqa_rows(rng))
     rows.extend(_decode_perrow_rows(rng))
     rows.extend(_decode_paged_rows(rng))
-    rows.extend(_sharded_decode_rows())
     rows.extend(_serving_occupancy_rows())
     rows.extend(_serving_longprompt_rows())
     rows.extend(_serving_prefix_router_rows())

@@ -1,6 +1,7 @@
 """Benchmark driver — one harness per paper table/figure + roofline.
 
-Prints a ``name,us_per_call,derived`` CSV summary at the end.
+Prints a ``name,us_per_call,derived`` CSV summary at the end, and exits
+non-zero when any phase failed.
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ def main() -> None:
     from benchmarks import (fig13_tablev, fig14_accuracy, fig15_gce,
                             kernels_bench, roofline, table_iv)
 
-    all_rows = []
+    all_rows, failed = [], []
     for name, mod in (("table_iv", table_iv), ("fig13_tablev", fig13_tablev),
                       ("fig15_gce", fig15_gce), ("kernels", kernels_bench),
                       ("fig14_accuracy", fig14_accuracy),
@@ -28,11 +29,14 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001
             print(f"  FAILED: {e}")
             all_rows.append((f"{name}/FAILED", 0.0, str(e)[:60]))
+            failed.append(name)
 
     print("\n# CSV summary")
     print("name,us_per_call,derived")
     for name, us, derived in all_rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        raise SystemExit(f"failed phases: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

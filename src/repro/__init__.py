@@ -2,7 +2,7 @@
 
 Layers:
   core/     — the paper's Compute-ACAM contribution (compiler, numerics)
-  kernels/  — Pallas TPU kernels (interpret-validated)
+  kernels/  — Pallas TPU kernels (interpreted on CPU, Mosaic on TPU)
   models/   — block-pattern transformer stack, digital + raceit exec modes
   configs/  — 10 assigned architectures + the paper's own models
   dist/     — sharding rules (DP/FSDP/TP/EP/SP), gradient compression

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import math
 from typing import Callable, Optional, Sequence
 
@@ -349,6 +350,7 @@ class _EvalResult:
 
 def _eval_map(idx_map, grid_idx, kvl, kvm, bt) -> _EvalResult:
     """Run a real index-map closure on abstract args, jnp proxied."""
+    idx_map = inspect.unwrap(idx_map)  # Pallas wraps maps in _IndexMapFunc
     args = list(grid_idx)
     extra = idx_map.__code__.co_argcount - len(args)
     args += [kvl, kvm, bt][:max(extra, 0)]
@@ -375,7 +377,7 @@ def _eval_map(idx_map, grid_idx, kvl, kvm, bt) -> _EvalResult:
 
 def _map_anchor(idx_map) -> tuple[str, int]:
     """(repo-relative path, line) of the *inner* map, unwrapping `_im`."""
-    fn = idx_map
+    fn = inspect.unwrap(idx_map)
     for cell in (fn.__closure__ or ()):
         if callable(getattr(cell, "cell_contents", None)):
             inner = cell.cell_contents
@@ -424,11 +426,13 @@ def analyze_call(probe: Probe, call: CapturedCall) -> tuple[list[Finding], dict]
                            paged.n_pages, mode, oob)
         return kvl, kvm, bt
 
-    specs = ([(r, s, ops_in[j]) for j, (r, s) in
+    # whole-array SMEM operands (scalars) have no index map to check
+    specs = [(r, s, o) for r, s, o in
+             [(r, s, ops_in[j]) for j, (r, s) in
               enumerate(zip(in_roles, call.in_specs))] +
-             [(r, s, o) for r, s, o in
-              zip(out_roles, call.out_specs,
-                  jax.tree_util.tree_leaves(call.out_shape))])
+             list(zip(out_roles, call.out_specs,
+                      jax.tree_util.tree_leaves(call.out_shape)))
+             if s.index_map is not None]
 
     # ---- P1: bounds over the whole grid x full prefetch domain -----------
     for role, spec, operand in specs:
@@ -579,7 +583,7 @@ def analyze_call(probe: Probe, call: CapturedCall) -> tuple[list[Finding], dict]
         if "smem" not in space:
             vmem += int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
     if vmem > probe.budget:
-        path, line = _map_anchor(call.in_specs[0].index_map)
+        path, line = _map_anchor(specs[0][1].index_map)
         findings.append(Finding(
             "kernelcheck", "KC106", path, line, f"{probe.name}:vmem",
             f"estimated VMEM footprint {vmem / MIB:.2f} MiB exceeds the "
@@ -803,6 +807,9 @@ def contracts_markdown(rows, coverage) -> str:
         specs = list(zip(in_roles, call.in_specs)) + \
             list(zip(out_roles, call.out_specs))
         for role, spec in specs:
+            if spec.index_map is None:
+                out.append(f"| {role} | whole array (SMEM) | — |")
+                continue
             path, line = _map_anchor(spec.index_map)
             out.append(f"| {role} | `{spec.block_shape}` | "
                        f"`{path}:{line}` |")

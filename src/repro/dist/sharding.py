@@ -27,9 +27,8 @@ Who consumes what (these are live call paths, not future plans):
   `MeshSpec.build()` to materialize the concrete `jax.sharding.Mesh`
   and `repro.dist.shard_map` to run per-shard kernel bodies over it.
 
-Also hosts the small jax-version compatibility shims (`shard_map`,
-`compat_make_mesh`) so model code and tests run on both the 0.4.x toolchain
-baked into this container and newer jax releases.
+Also hosts `make_mesh` (Auto axis types) and a `shard_map` whose
+replication check is off by default.
 """
 from __future__ import annotations
 
@@ -44,39 +43,29 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 __all__ = [
     "MeshContext", "MeshSpec", "ShardingPolicy", "constraint",
     "current_policy", "named_sharding_tree", "param_specs", "use_policy",
-    "shard_map", "compat_make_mesh",
+    "shard_map", "make_mesh",
 ]
 
 _DP_AXES = ("pod", "data")
 
 
 # --------------------------------------------------------------------------
-# jax version compatibility
+# mesh + shard_map
 # --------------------------------------------------------------------------
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` on new jax; experimental shard_map (check_rep) on old."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:  # pre-check_vma signature
-            pass
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """`jax.shard_map`, replication check off unless asked for (the kernel
+    bodies' pallas_calls carry no varying-axis annotations)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-def compat_make_mesh(shape, axis_names):
-    """jax.make_mesh with Auto axis types where the installed jax has them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axis_names,
-                                 axis_types=(axis_type.Auto,) * len(shape))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names, devices=None):
+    """`jax.make_mesh` with Auto axis types: the model stack places
+    activations with `with_sharding_constraint` and lets GSPMD propagate
+    the rest, which Explicit axes (jax's default) refuse."""
+    return jax.make_mesh(shape, axis_names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 # --------------------------------------------------------------------------
@@ -168,7 +157,7 @@ class MeshSpec:
                     f"but the process has {have}; run under XLA_FLAGS="
                     f"--xla_force_host_platform_device_count="
                     f"{self.n_devices} (or on that many real devices)")
-            cached = _BUILT_MESHES[self] = compat_make_mesh(
+            cached = _BUILT_MESHES[self] = make_mesh(
                 tuple(size for _, size in self.axes), self.axis_names)
         return cached
 

@@ -92,6 +92,7 @@ from repro.core import ops as acam_ops
 from repro.core.ops import LOGIT_FMT, PROB_FMT
 from repro.core.quant import PoTFormat
 
+from .acam_lut import TABLE_SHAPE, lane_lookup, lane_table
 from .runtime import resolve_interpret
 
 __all__ = ["acam_attention_codes", "acam_attention_decode_codes",
@@ -103,6 +104,7 @@ DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 DEFAULT_BLOCK_G = 8
 _LANES = 128
+_VMEM_TILE_ELEMS = 1 << 18  # elements of one compiled (bg, bq, bk) tile
 
 # every softmax configuration the staged acam_softmax accepts; the fused
 # kernels cover all of them (core.attention.fused_attention_supported is the
@@ -172,11 +174,25 @@ def _requant_code_table(cmax, prob_lut_vals):
 
     Elementwise application of a value-wise function commutes with the
     table, so gathering this is bit-identical to quantizing the
-    materialized probabilities with `quantize_tensor`.
+    materialized probabilities with `quantize_tensor`. Works on the table in
+    any layout (the kernels pass the (2, 128) lane table).
     """
     p_tab = prob_lut_vals.astype(jnp.float32) * PROB_FMT.scale
     return jnp.clip(jnp.round(p_tab / requant_scale(cmax)),
                     -128, 127).astype(jnp.int32)
+
+
+def _group_lens(kvlen_ref, g0, bg: int):
+    """Valid lengths of groups ``g0 .. g0+bg`` as a (bg, 1, 1) vector.
+
+    SMEM serves scalar loads only, so the vector is assembled from ``bg``
+    of them.
+    """
+    gi = jax.lax.broadcasted_iota(jnp.int32, (bg, 1, 1), 0)
+    lens = jnp.zeros((bg, 1, 1), jnp.int32)
+    for b in range(bg):
+        lens = jnp.where(gi == b, kvlen_ref[g0 + b], lens)
+    return lens
 
 
 def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
@@ -200,7 +216,7 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
     g = pl.program_id(1)
     i = pl.program_id(2)
     k = pl.program_id(3)
-    rows = pl.dslice((g * nq + i) * bg * bq, bg * bq)  # per-row scratch slots
+    tile = g * nq + i  # this tile's slot in the row-sum scratch
     # keys past the real/valid length carry no weight at all (they do not
     # exist in the oracle's input): static block padding, or — decode path —
     # the dynamic KV-cache fill level streamed in as a prefetched scalar
@@ -232,7 +248,7 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
 
     def row_lens():
         """Per-group valid lengths of this tile's rows: (bg, 1, 1)."""
-        return kvlen_ref[pl.dslice(g * bg, bg)].reshape(bg, 1, 1)
+        return _group_lens(kvlen_ref, g * bg, bg)
 
     def key_valid():
         kpos = k * bk + jax.lax.broadcasted_iota(jnp.int32, (bg, bq, bk), 2)
@@ -243,7 +259,7 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
     def tile_logit_codes():
         """matmul-1 + div-add: (bg, bq, bk) LOGIT codes."""
         r = jax.lax.dot_general(
-            q_ref[...].astype(jnp.int32), k_ref[...].astype(jnp.int32),
+            q_ref[...].astype(jnp.int8), k_ref[...].astype(jnp.int8),
             (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32)
         logits = r.astype(jnp.float32) * s1_ref[0, 0]
         if sqrt_d is not None:
@@ -263,7 +279,7 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
         return xc
 
     def load_row_sums():
-        return sum_ref[rows, :].reshape(bg, bq, 1)
+        return sum_ref[tile][:, :, None]
 
     # ---------------- pass A: streaming PoT row sum + global PROB max ------
     @pl.when(ph == 0)
@@ -279,31 +295,30 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
 
         @pl.when(k == 0)
         def _init_rows():
-            sum_ref[rows, :] = jnp.zeros((bg * bq, 1), jnp.float32)
+            sum_ref[tile] = jnp.zeros((bg, bq), jnp.float32)
             xmax_ref[...] = jnp.full((bg, bq, 1), LOGIT_FMT.code_min, jnp.int32)
 
         @guard_live
         def _accumulate():
             xc = tile_logit_codes()
             # exp_val_ref folds the exp LUT with its decode: one f32 gather
-            e_vals = exp_val_ref[xc + 128]
+            e_vals = lane_lookup(exp_val_ref[...], xc + 128)
             xmax_tile = xc
             if mask_keys:
                 valid = key_valid()
                 e_vals = jnp.where(valid, e_vals, 0.0)
                 xmax_tile = jnp.where(valid, xc, LOGIT_FMT.code_min)
-            sum_ref[rows, :] += jnp.sum(e_vals, axis=-1, keepdims=True
-                                        ).reshape(bg * bq, 1)
+            sum_ref[tile] += jnp.sum(e_vals, axis=-1)
             xmax_ref[...] = jnp.maximum(
                 xmax_ref[...], jnp.max(xmax_tile, axis=-1, keepdims=True))
 
         @pl.when(k == nk - 1)
         def _row_finish():
-            L = log_lut_ref[_pot_encode_sum(load_row_sums(), e_min,
-                                            octave_step)]
+            L = lane_lookup(log_lut_ref[...], _pot_encode_sum(
+                load_row_sums(), e_min, octave_step))
             dmax = jnp.clip(xmax_ref[...] - (L << frac_shift),
                             LOGIT_FMT.code_min, LOGIT_FMT.code_max)
-            c_row = prob_lut_ref[dmax + 128]
+            c_row = lane_lookup(prob_lut_ref[...], dmax + 128)
             rpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bg, bq, 1), 1)
             gpos = g * bg + jax.lax.broadcasted_iota(jnp.int32, (bg, bq, 1), 0)
             c_row = jnp.where((rpos < sq_real) & (gpos < g_real), c_row, 0)
@@ -325,15 +340,18 @@ def _attn_kernel(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref, q_ref,
         @guard_live
         def _accumulate():
             xc = tile_logit_codes()
-            L = log_lut_ref[_pot_encode_sum(load_row_sums(), e_min,
-                                            octave_step)]
+            L = lane_lookup(log_lut_ref[...], _pot_encode_sum(
+                load_row_sums(), e_min, octave_step))
             d = jnp.clip(xc - (L << frac_shift),
                          LOGIT_FMT.code_min, LOGIT_FMT.code_max)
-            pc = _requant_code_table(cmax_ref[0, 0], prob_lut_ref[...])[d + 128]
+            pc = lane_lookup(
+                _requant_code_table(cmax_ref[0, 0], prob_lut_ref[...]),
+                d + 128)
             if mask_keys:  # padded/invalid keys: PROB code 0 -> requant code 0
                 pc = jnp.where(key_valid(), pc, 0)
+            # requant codes are clipped to int8: an int8 MXU product
             acc_ref[...] += jax.lax.dot_general(
-                pc, v_ref[...].astype(jnp.int32),
+                pc.astype(jnp.int8), v_ref[...].astype(jnp.int8),
                 (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32)
 
         @pl.when(k == nk - 1)
@@ -365,7 +383,7 @@ def _attn_kernel_single(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref,
     mask_keys = (sk_real % bk != 0) or dyn_len
 
     r = jax.lax.dot_general(
-        q_ref[...].astype(jnp.int32), k_ref[...].astype(jnp.int32),
+        q_ref[...].astype(jnp.int8), k_ref[...].astype(jnp.int8),
         (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32)
     logits = r.astype(jnp.float32) * s1_ref[0, 0]
     if sqrt_d is not None:
@@ -379,24 +397,24 @@ def _attn_kernel_single(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref,
         kpos = jax.lax.broadcasted_iota(jnp.int32, (bg, bq, bk), 2)
         xc = jnp.where(kpos <= qpos + qoff_ref[0, 0], xc, LOGIT_FMT.code_min)
 
-    e_vals = exp_val_ref[xc + 128]
+    e_vals = lane_lookup(exp_val_ref[...], xc + 128)
     xmax_tile = xc
     if mask_keys:
         kpos = jax.lax.broadcasted_iota(jnp.int32, (bg, bq, bk), 2)
         if per_row:
-            lens = kvlen_ref[pl.dslice(0, bg)].reshape(bg, 1, 1)
+            lens = _group_lens(kvlen_ref, 0, bg)
             valid = kpos < lens
         else:
             valid = kpos < kvlen_ref[0]
         e_vals = jnp.where(valid, e_vals, 0.0)
         xmax_tile = jnp.where(valid, xc, LOGIT_FMT.code_min)
     S = jnp.sum(e_vals, axis=-1, keepdims=True)
-    L = log_lut_ref[_pot_encode_sum(S, e_min, octave_step)]
+    L = lane_lookup(log_lut_ref[...], _pot_encode_sum(S, e_min, octave_step))
 
     dmax = jnp.clip(jnp.max(xmax_tile, axis=-1, keepdims=True)
                     - (L << frac_shift),
                     LOGIT_FMT.code_min, LOGIT_FMT.code_max)
-    c_row = prob_lut_ref[dmax + 128]
+    c_row = lane_lookup(prob_lut_ref[...], dmax + 128)
     rpos = jax.lax.broadcasted_iota(jnp.int32, (bg, bq, 1), 1)
     gpos = jax.lax.broadcasted_iota(jnp.int32, (bg, bq, 1), 0)
     c_row = jnp.where((rpos < sq_real) & (gpos < g_real), c_row, 0)
@@ -406,11 +424,11 @@ def _attn_kernel_single(kvlen_ref, kvmax_ref, s1_ref, qoff_ref, ecmax_ref,
 
     d = jnp.clip(xc - (L << frac_shift),
                  LOGIT_FMT.code_min, LOGIT_FMT.code_max)
-    pc = _requant_code_table(cmax, prob_lut_ref[...])[d + 128]
+    pc = lane_lookup(_requant_code_table(cmax, prob_lut_ref[...]), d + 128)
     if mask_keys:
         pc = jnp.where(valid, pc, 0)
     o_ref[...] = jax.lax.dot_general(
-        pc, v_ref[...].astype(jnp.int32),
+        pc.astype(jnp.int8), v_ref[...].astype(jnp.int8),
         (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32)
     cmax_out_ref[0, 0] = cmax
 
@@ -514,6 +532,12 @@ def acam_attention_codes(
         bg = min(block_g, G)
         bk = min(block_k, max(_LANES, Sk))
     bq = min(block_q, max(8, Sq))
+    if not interpret:
+        # a compiled tile keeps about a dozen (bg, bq, bk) 32-bit
+        # temporaries live; cap each at 1 MiB to stay inside the 16 MiB
+        # scoped-VMEM default. Only bq shrinks: rows are independent, so
+        # the result is the same for every bq (bk sets the row-sum order)
+        bq = min(bq, max(8, (_VMEM_TILE_ELEMS // (bg * bk)) // 8 * 8))
     pad_g, pad_q, pad_k = (-G) % bg, (-Sq) % bq, (-Sk) % bk
     # lane-align the head dim only when compiling for real hardware; in
     # interpret mode the padding would just double the MXU work
@@ -583,8 +607,10 @@ def acam_attention_codes(
             return lambda p, g, i, k, kvl, kvm: f(p, g, i, k, kvl, kvm)
         return lambda p, g, i, k: f(p, g, i, k, None, None)
 
-    spec_scalar = pl.BlockSpec((1, 1), _im(lambda p, g, i, k, kvl, kvm: (0, 0)))
-    spec_lut = pl.BlockSpec((256,), _im(lambda p, g, i, k, kvl, kvm: (0,)))
+    # scalars live in SMEM, the only memory Mosaic loads scalars from
+    spec_scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
+    spec_lut = pl.BlockSpec(TABLE_SHAPE,
+                            _im(lambda p, g, i, k, kvl, kvm: (0, 0)))
 
     if paged:
         spb = page_size // bk  # key blocks per page
@@ -630,12 +656,19 @@ def acam_attention_codes(
     if mask is not None:
         mp = pad3(jnp.pad(mask.astype(jnp.int8),
                           ((0, 0), (0, pad_q), (0, pad_k))))
+        # key blocks become row blocks, (Gp, nk*Sqp, bk): a mask tile's
+        # lane dim is then the whole array's, which Mosaic accepts for
+        # any bk (a 64-row page gives 64-key blocks, not whole lane tiles)
+        mp = mp.reshape(Gp, Sqp, nk, bk).transpose(0, 2, 1, 3) \
+            .reshape(Gp, nk * Sqp, bk)
         in_specs.append(pl.BlockSpec(
-            (bg, bq, bk), _im(lambda p, g, i, k, kvl, kvm: (g, i, k))))
+            (bg, bq, bk),
+            _im(lambda p, g, i, k, kvl, kvm: (g, k * nq + i, 0))))
         operands.append(mp)
     in_specs += [spec_lut, spec_lut, spec_lut]
-    operands += [exp_val, jnp.asarray(log_lut, jnp.int32),
-                 jnp.asarray(prob_lut, jnp.int32)]
+    operands += [lane_table(exp_val),
+                 lane_table(jnp.asarray(log_lut, jnp.int32)),
+                 lane_table(jnp.asarray(prob_lut, jnp.int32))]
 
     if one_tile:  # single grid step, no scratch, no second key sweep
         kernel = functools.partial(
@@ -654,7 +687,9 @@ def acam_attention_codes(
             frac_shift=frac_shift, causal=causal, has_mask=mask is not None,
             dyn_len=dyn_len, per_row=per_row, skip_blocks=use_prefetch)
         scratch = [
-            pltpu.VMEM((Gp * Sqp, 1), jnp.float32),  # streaming PoT row sums
+            # streaming PoT row sums, rows on lanes: a (rows, 1) column
+            # would pad every row to a 128-lane tile in VMEM
+            pltpu.VMEM((ng * nq, bg, bq), jnp.float32),
             pltpu.VMEM((bg, bq, 1), jnp.int32),      # row logit max (pass A)
             pltpu.VMEM((bg, bq, Dp), jnp.int32),     # matmul-2 accumulator
             pltpu.SMEM((1, 1), jnp.int32),           # global PROB code max
@@ -680,13 +715,9 @@ def acam_attention_codes(
                 scratch_shapes=scratch),
             interpret=interpret)
     else:
-        kvlen_spec = pl.BlockSpec(
-            kv_len_val.shape, _im(lambda p, g, i, k, kvl, kvm: (0,)))
-        kvmax_spec = pl.BlockSpec(
-            (ng,), _im(lambda p, g, i, k, kvl, kvm: (0,)))
         call = pl.pallas_call(
             kernel, out_shape=out_shape, grid=grid,
-            in_specs=[kvlen_spec, kvmax_spec] + in_specs,
+            in_specs=[spec_scalar, spec_scalar] + in_specs,
             out_specs=out_specs,
             scratch_shapes=scratch, interpret=interpret)
     out, cmax = call(*operands)

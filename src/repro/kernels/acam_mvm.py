@@ -9,9 +9,11 @@ The DPE lane (paper §II-A/IV-A) adapted to the TPU memory hierarchy:
   pulses); every plane product is an int MXU matmul, digitized by the ADC
   transfer and consolidated with shift-&-add — bit-identical to the analog
   pipeline with an ideal converter.
-* ``exact`` mode folds all planes into one int8xint8->int32 MXU matmul (the
-  mathematically-equal fast path used by the serving stack); tests assert the
-  sliced and exact paths agree and match the pure-jnp oracle (ref.py).
+* ``exact`` mode folds all planes and offset corrections into one
+  int8xint8->int32 MXU matmul on the signed codes (the mathematically-equal
+  fast path; Mosaic refuses int32 matmul operands, so every product here
+  feeds int8); tests assert the sliced and exact paths agree and match the
+  pure-jnp oracle (ref.py).
 
 bk defaults to the crossbar height (128 rows) so one k-step == one crossbar.
 """
@@ -40,42 +42,47 @@ def _mvm_kernel(x_ref, w_ref, o_ref, acc_ref, *, cfg: CrossbarConfig,
 
     ox = 1 << (cfg.input_bits - 1)
     ow = 1 << (cfg.weight_bits - 1)
-    xu = x_ref[...].astype(jnp.int32) + ox  # offset encoding (ISAAC)
-    wu = w_ref[...].astype(jnp.int32) + ow
-    # zero out the offset on padded K rows so they contribute nothing
-    kpos = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, xu.shape, 1)
-    xu = jnp.where(kpos < k_real, xu, 0)
-    kposw = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, wu.shape, 0)
-    wu = jnp.where(kposw < k_real, wu, 0)
-
     dac_mask = (1 << cfg.dac_bits) - 1
     cell_mask = (1 << cfg.cell_bits) - 1
     p_max = cfg.rows * cell_mask * dac_mask
     levels = (1 << cfg.adc_bits) - 1
+    sliced = cfg.adc_mode == "quantize" and p_max > levels
 
-    acc = jnp.zeros(o_ref.shape, jnp.int32)
-    if cfg.adc_mode == "quantize" and p_max > levels:
+    if sliced:
+        xu = x_ref[...].astype(jnp.int32) + ox  # offset encoding (ISAAC)
+        wu = w_ref[...].astype(jnp.int32) + ow
+        # zero out the offset on padded K rows so they contribute nothing
+        kpos = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, xu.shape, 1)
+        xu = jnp.where(kpos < k_real, xu, 0)
+        kposw = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, wu.shape, 0)
+        wu = jnp.where(kposw < k_real, wu, 0)
         step = p_max / levels
+        acc = jnp.zeros(o_ref.shape, jnp.int32)
         for t in range(cfg.num_input_slices):      # temporal input slices
-            x_t = (xu >> (t * cfg.dac_bits)) & dac_mask
+            # planes are < 2^dac_bits / 2^cell_bits: int8 MXU operands
+            x_t = ((xu >> (t * cfg.dac_bits)) & dac_mask).astype(jnp.int8)
             for s in range(cfg.num_weight_slices):  # spatial weight slices
-                w_s = (wu >> (s * cfg.cell_bits)) & cell_mask
+                w_s = ((wu >> (s * cfg.cell_bits)) & cell_mask
+                       ).astype(jnp.int8)
                 p = jax.lax.dot(x_t, w_s, preferred_element_type=jnp.int32)
                 q = jnp.round(jnp.round(p / step) * step).astype(jnp.int32)
                 acc += q << (t * cfg.dac_bits + s * cfg.cell_bits)
+        # digital offset corrections (ones-column row-sum / precomputed
+        # col-sum)
+        rowsum = xu.sum(axis=1, keepdims=True)
+        colsum = wu.sum(axis=0, keepdims=True)
+        acc_ref[...] += acc - ow * rowsum - ox * colsum
     else:
-        # exact ADC: the shift-&-add over planes telescopes to one int matmul
-        acc = jax.lax.dot(xu, wu, preferred_element_type=jnp.int32)
-
-    # digital offset corrections (ones-column row-sum / precomputed col-sum)
-    rowsum = xu.sum(axis=1, keepdims=True)
-    colsum = wu.sum(axis=0, keepdims=True)
-    acc = acc - ow * rowsum - ox * colsum
-    acc_ref[...] += acc
+        # exact ADC: the shift-&-add over planes and the offset corrections
+        # telescope to one int8 x int8 -> int32 MXU matmul on the signed
+        # codes (zero-padded K rows contribute nothing)
+        acc_ref[...] += jax.lax.dot(
+            x_ref[...].astype(jnp.int8), w_ref[...].astype(jnp.int8),
+            preferred_element_type=jnp.int32)
 
     @pl.when(k_step == nsteps - 1)
     def _finish():
-        o_ref[...] = acc_ref[...] + k_real * ox * ow
+        o_ref[...] = acc_ref[...] + (k_real * ox * ow if sliced else 0)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "bm", "bn", "bk", "interpret"))

@@ -4,7 +4,8 @@ One VMEM pass per row-block executes all five stages —
 exp-LUT (PoT) -> adder-lane row sum -> log-LUT -> subtract -> exp-LUT —
 so the intermediate exponent codes never touch HBM (the XLA baseline spills
 them; see EXPERIMENTS.md §Perf). Tables are compiled by core.compiler and
-passed in as int32 operands resident in VMEM.
+passed in as int32 (2, 128) operands resident in VMEM and read with the
+lane gather of `acam_lut.lane_lookup`.
 
 Inputs are LOGIT_FMT (1-4-3) codes; output is PROB_FMT (0-0-8) codes.
 """
@@ -19,6 +20,7 @@ from jax.experimental import pallas as pl
 from repro.core import ops as acam_ops
 from repro.core.ops import LOGIT_FMT
 
+from .acam_lut import TABLE_SHAPE, lane_lookup, lane_table
 from .runtime import resolve_interpret
 
 LANES = 128
@@ -32,7 +34,7 @@ def _softmax_kernel(x_ref, exp_lut_ref, log_lut_ref, prob_lut_ref, o_ref, *,
     valid = cols < valid_cols
 
     # step 1: e = EXP(x) as PoT codes (bias to unsigned position first)
-    e_codes = exp_lut_ref[xc + 128]
+    e_codes = lane_lookup(exp_lut_ref[...], xc + 128)
     # adder lane works on decoded PoT values (code 0 == exactly 0)
     e_vals = jnp.where(e_codes == 0, 0.0,
                        jnp.exp2((e_codes - 1).astype(jnp.float32) * octave_step
@@ -45,11 +47,11 @@ def _softmax_kernel(x_ref, exp_lut_ref, log_lut_ref, prob_lut_ref, o_ref, *,
     s_codes = jnp.clip(jnp.round((jnp.log2(safe) - e_min) / octave_step),
                        0, 254).astype(jnp.int32) + 1
     s_codes = jnp.where(S < 2.0 ** (e_min - octave_step / 2), 0, s_codes)
-    L = log_lut_ref[s_codes]  # LOG_OUT (1-5-2) codes
+    L = lane_lookup(log_lut_ref[...], s_codes)  # LOG_OUT (1-5-2) codes
     # step 4: d = x - L in the logit grid (adder lane subtract)
     d = jnp.clip(xc - (L << frac_shift), -128, 127)
     # step 5: p = EXP(d) -> PROB codes
-    o_ref[...] = prob_lut_ref[d + 128].astype(o_ref.dtype)
+    o_ref[...] = lane_lookup(prob_lut_ref[...], d + 128).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_rows", "interpret"))
@@ -83,15 +85,15 @@ def acam_softmax_codes(x_codes: jax.Array, mode: str = "pot",
         out_shape=jax.ShapeDtypeStruct((Rp, Lp), jnp.int32),
         in_specs=[
             pl.BlockSpec((br, Lp), lambda i: (i, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
-            pl.BlockSpec((256,), lambda i: (0,)),
-            pl.BlockSpec((256,), lambda i: (0,)),
+            pl.BlockSpec(TABLE_SHAPE, lambda i: (0, 0)),
+            pl.BlockSpec(TABLE_SHAPE, lambda i: (0, 0)),
+            pl.BlockSpec(TABLE_SHAPE, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, Lp), lambda i: (i, 0)),
         grid=(Rp // br,),
         interpret=interpret,
-    )(xp, jnp.asarray(exp_op._lut, jnp.int32), jnp.asarray(log_op._lut, jnp.int32),
-      jnp.asarray(prob_op._lut, jnp.int32))
+    )(xp, *(lane_table(jnp.asarray(op._lut, jnp.int32))
+            for op in (exp_op, log_op, prob_op)))
     return out[:R, :L]
 
 

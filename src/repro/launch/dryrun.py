@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
-                           or "--xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves the distribution config is coherent on the
@@ -13,10 +9,14 @@ Usage:
   python -m repro.launch.dryrun --arch llama4-scout-17b-a16e --shape train_4k \
       --mesh single --out results/dryrun.json
   python -m repro.launch.dryrun --all             # every valid cell, both meshes
+
+The CLI compiles on 512 simulated host devices (``DRYRUN_XLA_FLAGS``
+overrides the XLA flags); importing the module changes nothing.
 """
 
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -49,7 +49,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str = "digital",
     from repro.configs.base import ExecConfig
     from repro.dist.sharding import MeshContext, use_policy
     from repro.launch import hlo_analysis, inputs
-    from repro.launch.mesh import (HBM_BW, ICI_LINK_BW, PEAK_BF16_FLOPS,
+    from repro.launch.mesh import (PRODUCTION_DEVICE_KIND, chip_peaks,
                                    make_production_mesh)
     from repro.models import Model
     from repro.train import optim, trainer
@@ -99,16 +99,15 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str = "digital",
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     hlo = hlo_analysis.analyze_hlo(compiled.as_text())
 
     mf = inputs.model_flops(cfg, spec["params"], shape)
     bytes_per_device = (ma.argument_size_in_bytes + ma.temp_size_in_bytes +
                         ma.output_size_in_bytes - ma.alias_size_in_bytes)
-    compute_s = hlo.flops / PEAK_BF16_FLOPS
-    memory_s = hlo.memory_bytes / HBM_BW
-    collective_s = hlo.collective_bytes / ICI_LINK_BW
+    peaks = chip_peaks(PRODUCTION_DEVICE_KIND)
+    compute_s = hlo.flops / peaks.bf16_flops
+    memory_s = hlo.memory_bytes / peaks.hbm_bytes_per_s
+    collective_s = hlo.collective_bytes / peaks.ici_link_bytes_per_s
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
@@ -123,7 +122,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str = "digital",
             "output_bytes": ma.output_size_in_bytes,
             "alias_bytes": ma.alias_size_in_bytes,
             "per_device_bytes": bytes_per_device,
-            "fits_16GB": bool(bytes_per_device < 16e9),
+            "fits_16GB": bool(bytes_per_device < peaks.hbm_bytes),
         },
         "xla_cost": {k: ca.get(k) for k in ("flops", "bytes accessed")},
         "hlo": hlo.to_dict(),
@@ -132,12 +131,14 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str = "digital",
         "useful_flops_ratio": (mf / n_chips) / hlo.flops if hlo.flops else None,
         "roofline": {**terms, "dominant": dominant,
                      "bound_s": max(terms.values()),
-                     "roofline_fraction": (mf / n_chips / PEAK_BF16_FLOPS)
+                     "roofline_fraction": (mf / n_chips / peaks.bf16_flops)
                                           / max(max(terms.values()), 1e-30)},
     }
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
+                               or "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

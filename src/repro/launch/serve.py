@@ -43,7 +43,7 @@ def parse_exec_plan(pairs: list[str]) -> tuple:
     return tuple(overrides)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--mode", default="digital",
@@ -121,8 +121,12 @@ def main():
                          "with XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=N")
     ap.add_argument("--set", nargs="*", default=[])
-    args = ap.parse_args()
+    return ap
 
+
+def serve(args: argparse.Namespace) -> tuple:
+    """Build the engine the arguments describe, serve the synthetic trace,
+    and print the report; returns (engine, scheduler, finished requests)."""
     import jax
     import numpy as np
 
@@ -249,6 +253,13 @@ def main():
                       f"{s['prefix_promotions']} promotions, "
                       f"{s['prefix_evictions']} evictions, "
                       f"{s['prefix_entries']} entries resident")
+    return eng, sched, done
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    serve(build_parser().parse_args(argv))
 
 
 if __name__ == "__main__":

@@ -21,12 +21,15 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--model", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
+    ap.add_argument("--ckpt-dir", default="results/launch_train")
     ap.add_argument("--set", nargs="*", default=[])
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.data import SyntheticLM

@@ -210,48 +210,6 @@ def _paged_soak(cfg, n_traces=3):
               f"(backend {s['decode_backend']})", flush=True)
 
 
-def _bench(reps=6):
-    """benchmarks/kernels_bench.py `kernel/attention_decode_tp` row: time
-    the jitted raceit_gqa_tp paged decode on a 4-way model mesh, after
-    re-asserting bitwise parity with the single-device raceit_gqa_paged
-    partner on the same operands. Interleaved min-of-N, us/call on
-    stdout (``TP_DECODE_US``) for the parent bench to collect."""
-    import time
-
-    cfg = GQA
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    scale = 1.0 / float(np.sqrt(hd))
-    key = jax.random.PRNGKey(3)
-    kq, kk, kv_ = jax.random.split(key, 3)
-    B, ps, blocks = 4, 64, 4
-    n_pages = 1 + B * blocks
-    q = _rand(kq, (B, 1, H, hd))
-    kpool = _rand(kk, (n_pages, ps, KV, hd))
-    vpool = _rand(kv_, (n_pages, ps, KV, hd))
-    bt = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(B, blocks)
-    kv_len = jnp.asarray([ps * blocks, ps * 2 + 5, ps - 1, 1], jnp.int32)
-
-    def call(plan):
-        return jax.jit(lambda: plan.attention_decode(
-            q, kpool, vpool, kv_len=kv_len, scale=scale,
-            block_table=bt, page_size=ps))
-
-    ref_fn = call(resolve_plan(cfg, _mesh_exec(0)))
-    tp_plan = resolve_plan(cfg, _mesh_exec(4))
-    assert tp_plan.backend("attention_decode") == "raceit_gqa_tp"
-    tp_fn = call(tp_plan)
-    _assert_bitwise(ref_fn(), tp_fn(), "bench paged decode model=4")
-    best = {"ref": float("inf"), "tp": float("inf")}
-    for _ in range(reps):
-        for name, fn in (("ref", ref_fn), ("tp", tp_fn)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            best[name] = min(best[name], time.perf_counter() - t0)
-    print(f"TP_DECODE_US {best['tp'] * 1e6:.1f}")
-    print(f"REF_DECODE_US {best['ref'] * 1e6:.1f}")
-    print("BENCH_OK")
-
-
 def main():
     mode = sys.argv[1] if len(sys.argv) > 1 else "ops"
     assert len(jax.devices()) == 8, jax.devices()
@@ -264,8 +222,6 @@ def main():
             _token_parity(cfg)
         _paged_soak(GQA)
         print("SOAK_OK")
-    elif mode == "bench":
-        _bench()
     else:
         raise SystemExit(f"unknown mode {mode!r}")
 

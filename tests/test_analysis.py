@@ -47,7 +47,8 @@ def test_every_pallas_kernel_covered(analysis_results):
         "src/repro/kernels/acam_softmax.py",
     }
     assert cov["kernelcheck.spec_sites"] >= 26
-    assert cov["kernelcheck.index_map_sites"] >= 20
+    # scalar operands are whole-array SMEM specs, with no index map
+    assert cov["kernelcheck.index_map_sites"] >= 18
     assert cov["kernelcheck.frontier_domains"] >= 60
     assert cov["kernelcheck.grid_points"] >= 200
 

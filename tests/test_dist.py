@@ -21,8 +21,8 @@ ROOT = Path(__file__).resolve().parent.parent
 
 
 def _mesh11():
-    from repro.dist.sharding import compat_make_mesh
-    return compat_make_mesh((1, 1), ("data", "model"))
+    from repro.dist.sharding import make_mesh
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_specs_rules(key):
@@ -95,16 +95,15 @@ def test_policy_drops_nondivisible_axes():
 @pytest.mark.slow
 def test_dryrun_subprocess_small_mesh():
     """End-to-end dry-run machinery on a forced 8-device CPU mesh."""
-    env = {"DRYRUN_XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
            "HOME": "/tmp"}
     code = (
-        "import os;"
-        "os.environ['XLA_FLAGS']=os.environ['DRYRUN_XLA_FLAGS'];"
-        "import repro.launch.dryrun as dr, repro.launch.mesh as lm, jax;"
+        "import repro.launch.dryrun as dr, repro.launch.mesh as lm;"
         "lm.make_production_mesh = (lambda *, multi_pod=False: "
-        "jax.make_mesh((2,2,2),('pod','data','model')) if multi_pod else "
-        "jax.make_mesh((4,2),('data','model')));"
+        "lm.make_mesh((2,2,2),('pod','data','model')) if multi_pod else "
+        "lm.make_mesh((4,2),('data','model')));"
         "r = dr.run_cell('olmo-1b','train_4k','single',"
         "overrides={'n_layers':2,'d_model':128,'n_heads':4,'n_kv_heads':4,"
         "'d_ff':256,'vocab_size':512});"
@@ -119,3 +118,25 @@ def test_dryrun_subprocess_small_mesh():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SUBPROCESS_OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_chip_peaks_known_and_unknown_kinds():
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, chip_peaks
+    assert chip_peaks(PRODUCTION_DEVICE_KIND).hbm_bytes == 16e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("TPU v4")
+
+
+def test_compile_cache_follows_env_var_else_checkout(monkeypatch, tmp_path):
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was  # nothing set
+        monkeypatch.delenv(cc.ENV_VAR)
+        fixed = str(ROOT / ".jax_cache")
+        assert cc.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

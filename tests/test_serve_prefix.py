@@ -256,7 +256,7 @@ def test_quarantine_releases_shared_keeps_cache_servable():
     surviving slot."""
     from repro.hw.noise import fault_rows, site_key
 
-    eng = _faulty_engine(0.5)
+    eng = _faulty_engine(0.5, seed=1)
     cb = ContinuousBatcher(eng, n_slots=2, page_size=PS,
                            n_pages=1 + 2 * (MAX_LEN // PS))
     nz = eng.plan.exec_cfg.noise

@@ -32,7 +32,7 @@ CHILD = ROOT / "tests" / "_sharded_parity_child.py"
 
 def _run_child(mode, sentinel):
     env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
-           "HOME": "/tmp"}
+           "HOME": "/tmp", "JAX_PLATFORMS": "cpu"}
     out = subprocess.run([sys.executable, str(CHILD), mode], env=env,
                          capture_output=True, text=True, timeout=900)
     assert sentinel in out.stdout, (

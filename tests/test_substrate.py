@@ -71,8 +71,8 @@ def test_elastic_restore_resharding(tmp_path):
     mgr = CheckpointManager(tmp_path)
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(1, tree)
-    from repro.dist.sharding import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("model",))
+    from repro.dist.sharding import make_mesh
+    mesh = make_mesh((1,), ("model",))
     shard = {"w": NamedSharding(mesh, P("model", None))}
     restored, _ = mgr.restore(tree, shardings=shard)
     np.testing.assert_array_equal(np.asarray(restored["w"]),
