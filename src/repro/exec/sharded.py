@@ -181,10 +181,11 @@ def _tp_fused_decode(q, k, v, kv_len, scale, plan, pad_valid=None):
         smax, kv = k.shape[1], k.shape[2]
         rep = h // kv
         qq = kops.tp_quantize_tensor(q, AXIS)
-        k_codes, k_scale = kops.tp_masked_prefix_quantize(
-            k.astype(jnp.float32), kvl, AXIS, axis=1)
-        v_codes, v_scale = kops.tp_masked_prefix_quantize(
-            v.astype(jnp.float32), kvl, AXIS, axis=1)
+        with jax.named_scope("kv_pool"):
+            k_codes, k_scale = kops.tp_masked_prefix_quantize(
+                k.astype(jnp.float32), kvl, AXIS, axis=1)
+            v_codes, v_scale = kops.tp_masked_prefix_quantize(
+                v.astype(jnp.float32), kvl, AXIS, axis=1)
         fold = lambda c: jnp.repeat(c, rep, axis=2).transpose(
             0, 2, 1, 3).reshape(b * h, smax, hd)
         mask = None
@@ -227,10 +228,11 @@ def _tp_gqa_decode(q, k, v, kv_len, scale, plan, pad_valid=None):
         smax, kv = k.shape[1], k.shape[2]
         rep = h // kv
         qq = kops.tp_quantize_tensor(q, AXIS)
-        k_codes, k_scale = kops.tp_masked_prefix_quantize(
-            k.astype(jnp.float32), kvl, AXIS, axis=1)
-        v_codes, v_scale = kops.tp_masked_prefix_quantize(
-            v.astype(jnp.float32), kvl, AXIS, axis=1)
+        with jax.named_scope("kv_pool"):
+            k_codes, k_scale = kops.tp_masked_prefix_quantize(
+                k.astype(jnp.float32), kvl, AXIS, axis=1)
+            v_codes, v_scale = kops.tp_masked_prefix_quantize(
+                v.astype(jnp.float32), kvl, AXIS, axis=1)
         to_groups = lambda c: c.transpose(0, 2, 1, 3).reshape(b * kv, smax, hd)
         mask = None
         if rest:
@@ -274,12 +276,13 @@ def _tp_paged_decode(q, k_pool, v_pool, kv_len, scale, plan, pad_valid=None,
         b, h, sq, hd = q.shape
         n_pages, ps, kv, _ = k_pool.shape
         rep = h // kv
-        pv = kops.page_valid_lengths(bt, kvl, n_pages, ps)
         qq = kops.tp_quantize_tensor(q, AXIS)
-        k_codes, k_scale = kops.tp_masked_page_quantize(
-            k_pool.astype(jnp.float32), pv, AXIS)
-        v_codes, v_scale = kops.tp_masked_page_quantize(
-            v_pool.astype(jnp.float32), pv, AXIS)
+        with jax.named_scope("kv_pool"):
+            pv = kops.page_valid_lengths(bt, kvl, n_pages, ps)
+            k_codes, k_scale = kops.tp_masked_page_quantize(
+                k_pool.astype(jnp.float32), pv, AXIS)
+            v_codes, v_scale = kops.tp_masked_page_quantize(
+                v_pool.astype(jnp.float32), pv, AXIS)
         sk = bt.shape[1] * ps
         if gqa:
             to_rows = lambda c: c.transpose(0, 2, 1, 3).reshape(

@@ -186,9 +186,12 @@ def expand_row_lens(kv_len: jax.Array, rep: int) -> jax.Array:
 def _decode_quantize_operands(q, k, v, kv_len):
     """Shared decode-wrapper prolog: q whole-tensor int8, k/v valid-prefix
     int8 (the single point of truth for both the flat and GQA wrappers —
-    their bit-identical contract starts with identical codes and scales)."""
-    return (quantize_tensor(q, bits=8), masked_prefix_quantize(k, kv_len),
-            masked_prefix_quantize(v, kv_len))
+    their bit-identical contract starts with identical codes and scales).
+    The cache's quantization reports under the ``kv_pool`` scope."""
+    qq = quantize_tensor(q, bits=8)
+    with jax.named_scope("kv_pool"):
+        return (qq, masked_prefix_quantize(k, kv_len),
+                masked_prefix_quantize(v, kv_len))
 
 
 @partial(jax.jit, static_argnames=("softmax_mode", "fold_scale",
@@ -253,11 +256,14 @@ def _paged_quantize_operands(q, k_pool, v_pool, block_table, kv_len):
     """Paged decode-wrapper prolog: q whole-tensor int8, pooled k/v per-page
     int8 with scales over the union of live page entries — bit-identical to
     `_decode_quantize_operands` on the contiguous gather of the same table
-    (the paged wrappers' parity contract starts here)."""
-    pv = page_valid_lengths(block_table, kv_len,
-                            k_pool.shape[0], k_pool.shape[1])
-    return (quantize_tensor(q, bits=8), masked_page_quantize(k_pool, pv),
-            masked_page_quantize(v_pool, pv))
+    (the paged wrappers' parity contract starts here). The pool's
+    quantization reports under the ``kv_pool`` scope."""
+    qq = quantize_tensor(q, bits=8)
+    with jax.named_scope("kv_pool"):
+        pv = page_valid_lengths(block_table, kv_len,
+                                k_pool.shape[0], k_pool.shape[1])
+        return (qq, masked_page_quantize(k_pool, pv),
+                masked_page_quantize(v_pool, pv))
 
 
 @partial(jax.jit, static_argnames=("softmax_mode", "fold_scale",
@@ -306,14 +312,16 @@ def raceit_attention_decode_paged(
         _paged_quantize_operands(q, k_pool, v_pool, block_table, kv_len)
     # flat grid layout: groups are query heads, so each physical page's
     # stripe row page*H + h holds KV head h//rep (codes repeated, pool not)
-    to_rows = lambda c: jnp.repeat(c, rep, axis=2).transpose(0, 2, 1, 3) \
-        .reshape(n_pages * H, ps, hd)
+    with jax.named_scope("kv_pool"):
+        k_rows, v_rows = (jnp.repeat(c, rep, axis=2).transpose(0, 2, 1, 3)
+                          .reshape(n_pages * H, ps, hd)
+                          for c in (k_codes, v_codes))
     if mask is not None:
         Sk = block_table.shape[1] * ps
         mask = jnp.broadcast_to(mask[:, None], (B, H, Sq, Sk)) \
             .reshape(B * H, Sq, Sk)
     out32, cmax = acam_attention_codes(
-        qq.codes.reshape(B * H, Sq, D), to_rows(k_codes), to_rows(v_codes),
+        qq.codes.reshape(B * H, Sq, D), k_rows, v_rows,
         qq.scale * k_scale, mask, kv_len=expand_row_lens(kv_len, H),
         mode=softmax_mode, scale_by_sqrt_d=None if fold_scale else D,
         block_k=block_k or DEFAULT_BLOCK_K, block_g=block_g or DEFAULT_BLOCK_G,
@@ -362,14 +370,16 @@ def raceit_attention_decode_gqa_paged(
     rep = H // KV
     qq, (k_codes, k_scale), (v_codes, v_scale) = \
         _paged_quantize_operands(q, k_pool, v_pool, block_table, kv_len)
-    to_rows = lambda c: c.transpose(0, 2, 1, 3).reshape(n_pages * KV, ps, hd)
+    with jax.named_scope("kv_pool"):
+        k_rows, v_rows = (c.transpose(0, 2, 1, 3).reshape(n_pages * KV, ps, hd)
+                          for c in (k_codes, v_codes))
     if mask is not None:
         Sk = block_table.shape[1] * ps
         mask = jnp.broadcast_to(mask[:, None], (B, KV, rep, Sk)) \
             .reshape(B * KV, rep, Sk)
     out32, cmax = acam_attention_decode_gqa_codes(
         qq.codes.reshape(B, KV, rep, D).reshape(B * KV, rep, D),
-        to_rows(k_codes), to_rows(v_codes), qq.scale * k_scale,
+        k_rows, v_rows, qq.scale * k_scale,
         expand_row_lens(kv_len, KV), mask=mask,
         mode=softmax_mode, scale_by_sqrt_d=None if fold_scale else D,
         block_k=block_k or DEFAULT_BLOCK_K, block_g=block_g or DEFAULT_BLOCK_G,

@@ -225,7 +225,11 @@ def serve(args: argparse.Namespace) -> tuple:
     if args.continuous:
         occ = (sched.decode_tokens / sched.decode_steps
                if sched.decode_steps else float("nan"))
-        extra = (f", {sched.chunk_calls} chunk calls" if sched.paged else "")
+        extra = ""
+        if sched.paged and sched.chunk_calls:
+            fill = 100.0 * sched.chunk_fed_tokens / sched.chunk_slot_tokens
+            extra = (f", {sched.chunk_calls} chunk calls ({fill:.1f}% of "
+                     f"their slot rows fed)")
         print(f"[serve] continuous: {sched.prefills} prefills{extra}, "
               f"{sched.decode_steps} decode steps, "
               f"{occ:.2f} tokens/step occupancy")

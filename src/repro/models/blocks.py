@@ -76,13 +76,16 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
     plan = _mixer_plan(plan, mixer)
     h = layers.apply_norm(p["norm1"], x, cfg)
     if mixer in ("attn", "attn_local"):
-        m, new_cache = layers.attention(
-            p["attn"], h, cfg=cfg, plan=plan, positions=positions,
-            local=(mixer == "attn_local"),
-            cache=cache.get("attn") if cache else None, pad_lens=pad_lens,
-            pad_prompt_len=pad_prompt_len, slot_lens=slot_lens,
-            block_table=block_table, page_size=page_size,
-            chunk_offs=chunk_offs)
+        # layer scopes (attn, mlp; kv_pool inside attention) name the
+        # layer's device work in the HLO metadata a profile reports
+        with jax.named_scope("attn"):
+            m, new_cache = layers.attention(
+                p["attn"], h, cfg=cfg, plan=plan, positions=positions,
+                local=(mixer == "attn_local"),
+                cache=cache.get("attn") if cache else None,
+                pad_lens=pad_lens, pad_prompt_len=pad_prompt_len,
+                slot_lens=slot_lens, block_table=block_table,
+                page_size=page_size, chunk_offs=chunk_offs)
         if cache is not None:
             new_cache = {"attn": new_cache}
     elif mixer == "mamba":
@@ -96,16 +99,19 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
 
     if "cross" in p and enc_kv is not None:
         hx = layers.apply_norm(p["norm_x"], x, cfg)
-        cx, _ = layers.attention(p["cross"], hx, cfg=cfg, plan=plan,
-                                 positions=positions, cross_kv=enc_kv)
+        with jax.named_scope("attn"):
+            cx, _ = layers.attention(p["cross"], hx, cfg=cfg, plan=plan,
+                                     positions=positions, cross_kv=enc_kv)
         x = x + cx
 
     if ffn_kind == "dense":
         h2 = layers.apply_norm(p["norm2"], x, cfg)
-        x = x + layers.ffn(p["ffn"], h2, cfg, plan)
+        with jax.named_scope("mlp"):
+            x = x + layers.ffn(p["ffn"], h2, cfg, plan)
     elif ffn_kind == "moe":
         h2 = layers.apply_norm(p["norm2"], x, cfg)
-        x = x + moe_mod.moe(p["moe"], h2, cfg, plan, mesh_ctx)
+        with jax.named_scope("mlp"):
+            x = x + moe_mod.moe(p["moe"], h2, cfg, plan, mesh_ctx)
     # sequence-parallel residual stream: the carried activation (and thus the
     # remat stash) lives sharded over "model"; XLA inserts AG/RS at the
     # boundaries that need full sequence (Megatron-SP pattern).
@@ -292,9 +298,12 @@ def apply_stack(params: Params, x: jax.Array, *, cfg: ModelConfig,
         body_fn = _remat_wrap(body, cfg) if use_remat else body
         scan_caches = tuple(caches["scan"]) if has_cache else tuple(
             {} for _ in range(P))
-        x, new_scan = jax.lax.scan(
-            body_fn, x, (tuple(params["scan"]), scan_caches),
-            unroll=cfg.scan_unroll)
+        # what the scan adds itself (slicing each layer's weights and
+        # cache in, stacking the updated cache out) reports as layer_scan
+        with jax.named_scope("layer_scan"):
+            x, new_scan = jax.lax.scan(
+                body_fn, x, (tuple(params["scan"]), scan_caches),
+                unroll=cfg.scan_unroll)
     else:
         new_scan = ()
 

@@ -688,61 +688,71 @@ def attention(
                              "does not apply")
 
     new_cache = None
-    if paged:
-        ps = int(page_size)
-        lens = jnp.asarray(slot_lens, jnp.int32)
-        bt = jnp.asarray(block_table, jnp.int32)
-        rows = jnp.arange(b)
-        if chunk_offs is not None:
-            # chunked prefill: fenced physical targets from the shared
-            # routing helper (trash page 0 for dead or overflow columns)
-            pages, slot = paged_write_targets_chunk(bt, lens, chunk_offs,
-                                                    sq, ps)
-            ck = cache["k"].at[pages, slot].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[pages, slot].set(v.astype(cache["v"].dtype))
-        else:
-            if sq != 1:
-                raise ValueError("paged caches take Sq=1 decode steps or "
-                                 "chunked prefill (chunk_offs); whole-prompt "
-                                 "prefill goes through Model.prefill_chunk")
-            pages, slot = paged_write_targets_decode(bt, lens, ps)
-            ck = cache["k"].at[pages, slot].set(
-                k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[pages, slot].set(
-                v[:, 0].astype(cache["v"].dtype))
-        new_cache = {"k": ck, "v": cv, "idx": lens}
-        k, v = ck, cv
-    elif cache is not None and cross_kv is None:
-        idx = cache["idx"]
-        per_slot = getattr(idx, "ndim", 0) == 1  # slot-pool cache
-        L = cache["k"].shape[1]
-        if sq >= L:
-            # prefill past the buffer (ring caches of local layers): keep the
-            # last L rotated-in-place entries; RoPE is pre-applied so storage
-            # order is irrelevant under the all-valid mask.
-            ck = k[:, -L:].astype(cache["k"].dtype)
-            cv = v[:, -L:].astype(cache["v"].dtype)
-        elif per_slot:
-            # per-slot write indices: each row's new token lands at its own
-            # column (slots fill independently under continuous batching)
-            if sq != 1:
-                raise ValueError("per-slot caches only take Sq=1 decode "
-                                 "steps; prefill into a slot goes through "
-                                 "a solo prefill + row scatter "
-                                 "(repro.serve.continuous)")
-            pos = idx % L if local else idx
+    # the cache writes (and, inside the paged decode wrappers, the
+    # pool's quantization) report under kv_pool
+    with jax.named_scope("kv_pool"):
+        if paged:
+            ps = int(page_size)
+            lens = jnp.asarray(slot_lens, jnp.int32)
+            bt = jnp.asarray(block_table, jnp.int32)
             rows = jnp.arange(b)
-            ck = cache["k"].at[rows, pos].set(k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[rows, pos].set(v[:, 0].astype(cache["v"].dtype))
-        else:
-            pos = idx % L if local else idx  # ring write for local layers
-            ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                              (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                              (0, pos, 0, 0))
-        new_cache = {"k": ck, "v": cv, "idx": idx + sq}
-        if sq == 1:  # decode attends through the cache
+            if chunk_offs is not None:
+                # chunked prefill: fenced physical targets from the shared
+                # routing helper (trash page 0 for dead or overflow columns)
+                pages, slot = paged_write_targets_chunk(bt, lens, chunk_offs,
+                                                        sq, ps)
+                ck = cache["k"].at[pages, slot].set(
+                    k.astype(cache["k"].dtype))
+                cv = cache["v"].at[pages, slot].set(
+                    v.astype(cache["v"].dtype))
+            else:
+                if sq != 1:
+                    raise ValueError(
+                        "paged caches take Sq=1 decode steps or chunked "
+                        "prefill (chunk_offs); whole-prompt prefill goes "
+                        "through Model.prefill_chunk")
+                pages, slot = paged_write_targets_decode(bt, lens, ps)
+                ck = cache["k"].at[pages, slot].set(
+                    k[:, 0].astype(cache["k"].dtype))
+                cv = cache["v"].at[pages, slot].set(
+                    v[:, 0].astype(cache["v"].dtype))
+            new_cache = {"k": ck, "v": cv, "idx": lens}
             k, v = ck, cv
+        elif cache is not None and cross_kv is None:
+            idx = cache["idx"]
+            per_slot = getattr(idx, "ndim", 0) == 1  # slot-pool cache
+            L = cache["k"].shape[1]
+            if sq >= L:
+                # prefill past the buffer (ring caches of local layers):
+                # keep the last L rotated-in-place entries; RoPE is
+                # pre-applied so storage order is irrelevant under the
+                # all-valid mask.
+                ck = k[:, -L:].astype(cache["k"].dtype)
+                cv = v[:, -L:].astype(cache["v"].dtype)
+            elif per_slot:
+                # per-slot write indices: each row's new token lands at its
+                # own column (slots fill independently under continuous
+                # batching)
+                if sq != 1:
+                    raise ValueError("per-slot caches only take Sq=1 decode "
+                                     "steps; prefill into a slot goes through "
+                                     "a solo prefill + row scatter "
+                                     "(repro.serve.continuous)")
+                pos = idx % L if local else idx
+                rows = jnp.arange(b)
+                ck = cache["k"].at[rows, pos].set(
+                    k[:, 0].astype(cache["k"].dtype))
+                cv = cache["v"].at[rows, pos].set(
+                    v[:, 0].astype(cache["v"].dtype))
+            else:
+                pos = idx % L if local else idx  # ring write for local layers
+                ck = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
+            new_cache = {"k": ck, "v": cv, "idx": idx + sq}
+            if sq == 1:  # decode attends through the cache
+                k, v = ck, cv
 
     scale = 1.0 / math.sqrt(hd)
 

@@ -176,9 +176,11 @@ class Model:
                slot_lens=None, block_table=None, page_size=None,
                chunk_offs=None):
         cfg = self.cfg
-        x = layers.embed(params["embed"], tokens,
-                         positions if positions.ndim == 2 else positions[0], cfg)
-        x = x.astype(_dtype(cfg.compute_dtype))
+        with jax.named_scope("embed"):
+            x = layers.embed(params["embed"], tokens,
+                             positions if positions.ndim == 2
+                             else positions[0], cfg)
+            x = x.astype(_dtype(cfg.compute_dtype))
 
         if cfg.is_encoder_decoder:
             if caches is not None and "enc_kv" in caches:
@@ -214,6 +216,10 @@ class Model:
         x = layers.apply_norm(params["final_norm"], x, cfg)
         return x, new_caches
 
+    def _unembed(self, params: Params, x: jax.Array) -> jax.Array:
+        with jax.named_scope("unembed"):
+            return layers.unembed(params["embed"], x, self.cfg, self.plan)
+
     # ---------------------------------------------------------------- public
     def forward(self, params: Params, batch: dict, use_remat: bool = True):
         tokens = batch["tokens"]
@@ -222,7 +228,7 @@ class Model:
             positions = self._positions(tokens)
         x, _ = self._trunk(params, tokens, positions, None,
                            batch.get("enc_feats"), use_remat)
-        return layers.unembed(params["embed"], x, self.cfg, self.plan)
+        return self._unembed(params, x)
 
     def init_slot_cache(self, n_slots: int, max_len: int, dtype=None,
                         page_size: Optional[int] = None,
@@ -300,7 +306,7 @@ class Model:
                 for (k, v), c in zip(self._enc_kv(params, enc_out), cache["enc_kv"])])
         x, new_cache = self._trunk(params, tokens, positions, cache, None,
                                    False, pad_lens=pad_lens)
-        logits = layers.unembed(params["embed"], x[:, -1:], self.cfg, self.plan)
+        logits = self._unembed(params, x[:, -1:])
         return logits, new_cache
 
     def decode_step(self, params: Params, token: jax.Array, cache: Params,
@@ -356,7 +362,7 @@ class Model:
                                    slot_lens=slot_lens,
                                    block_table=block_table,
                                    page_size=page_size)
-        logits = layers.unembed(params["embed"], x, self.cfg, self.plan)
+        logits = self._unembed(params, x)
         return logits, new_cache
 
     def prefill_chunk(self, params: Params, tokens: jax.Array, cache: Params,
@@ -390,7 +396,7 @@ class Model:
         last = jnp.maximum(feed - 1, 0)[:, None, None]
         x_last = jnp.take_along_axis(x, jnp.broadcast_to(
             last, (x.shape[0], 1, x.shape[2])), axis=1)
-        logits = layers.unembed(params["embed"], x_last, self.cfg, self.plan)
+        logits = self._unembed(params, x_last)
         return logits, new_cache
 
     def _cache_index(self, cache: Params):

@@ -103,6 +103,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import compile_count
+
 from .batching import Request, RequestError
 from .engine import GenerationEngine
 from .metrics import ServeMetrics
@@ -111,6 +113,10 @@ from .prefix import PrefixCache
 from .router import AdmissionRouter
 
 __all__ = ["ContinuousBatcher"]
+
+# a host span on the profiler's timeline; costs a flag check when no
+# trace is being recorded
+_span = jax.profiler.TraceAnnotation
 
 
 def _scatter_row(pool, row, slot):
@@ -183,7 +189,9 @@ class ContinuousBatcher:
       longest prompt queued at the first admission.
 
     Occupancy counters (`decode_steps`, `decode_tokens`, `prefills`,
-    `chunk_calls`, `tokens_out`, `model_calls`) feed the
+    `chunk_calls`, `tokens_out`, `model_calls`; in paged mode also
+    `chunk_fed_tokens` over `chunk_slot_tokens`, the chunk calls' fill)
+    feed the
     ``serve/continuous_occupancy`` benchmark rows: decode tokens per
     decode step on a mixed-length trace is the metric the bucketed
     scheduler loses to slot idling (prefill is accounted separately — it
@@ -279,6 +287,8 @@ class ContinuousBatcher:
         self.decode_tokens = 0
         self.prefills = 0
         self.chunk_calls = 0
+        self.chunk_fed_tokens = 0   # prompt tokens the chunk calls fed
+        self.chunk_slot_tokens = 0  # n_slots x prefill_chunk per chunk call
         self.tokens_out = 0
 
     # ------------------------------------------------------------ lifecycle
@@ -395,7 +405,7 @@ class ContinuousBatcher:
             self.done[req.rid] = req
             self.metrics.on_reject(req.rid)
         else:
-            self.metrics.on_submit(req.rid, req.tenant)
+            self.metrics.on_submit(req.rid)
 
     def _lock_prefill_len(self):
         if self.prefill_len is not None:
@@ -587,53 +597,60 @@ class ContinuousBatcher:
                    if s is not None and s.fed < len(s.req.prompt)]
         if not feeding:
             return
-        eng = self.engine
-        C = self.prefill_chunk
-        toks = np.full((self.n, C), self.pad_id, np.int32)
-        offs = np.zeros(self.n, np.int32)
-        feeds = np.zeros(self.n, np.int32)
-        bt = np.zeros_like(self.block_table)
-        for i in feeding:
-            st = self.slots[i]
-            feed = min(C, len(st.req.prompt) - st.fed)
-            toks[i, :feed] = st.req.prompt[st.fed: st.fed + feed]
-            offs[i] = st.fed
-            feeds[i] = feed
-            bt[i] = self.block_table[i]
-        logits, self.cache = eng._prefill_chunk(
-            eng.params, jnp.asarray(toks), self.cache, jnp.asarray(offs),
-            jnp.asarray(feeds), jnp.asarray(bt), self.page_size)
-        self.chunk_calls += 1
-        bad = eng.nonfinite_rows(logits[:, -1])
-        self.rng, sub = jax.random.split(self.rng)
-        sampled = np.asarray(eng._sample(logits[:, -1], sub))
-        for i in feeding:
-            st = self.slots[i]
-            if bad[i]:
-                # unlike the contiguous solo admission prefill, the chunk
-                # call shares the pool's (n_slots,) row geometry — a
-                # faulted row is dead for the run exactly like a decode
-                # fault, so quarantine (and leak the pages)
-                st.req.error = RequestError(
-                    rid=st.req.rid, stage="prefill", step=st.fed,
-                    reason="non-finite logits from a prefill chunk")
-                self.done[st.req.rid] = st.req
-                self.metrics.on_error(st.req.rid)
-                self._quarantine(i)
-                continue
-            st.fed += int(feeds[i])
-            self._promote_streamed(i)
-            if st.fed == len(st.req.prompt):
-                # prompt complete: the chunk's last fed position IS the
-                # prompt's last position, so its logits seed generation
-                self.prefills += 1
-                tok0 = int(sampled[i])
-                st.tokens.append(tok0)
-                st.length = len(st.req.prompt)
-                self.tokens_out += 1
-                self.metrics.on_first_token(st.req.rid, st.req.tenant)
-                self.tok[i, 0] = tok0
-                self._retire_if_done(i)
+        with _span("batcher.chunk") as span:
+            eng = self.engine
+            C = self.prefill_chunk
+            toks = np.full((self.n, C), self.pad_id, np.int32)
+            offs = np.zeros(self.n, np.int32)
+            feeds = np.zeros(self.n, np.int32)
+            bt = np.zeros_like(self.block_table)
+            for i in feeding:
+                st = self.slots[i]
+                feed = min(C, len(st.req.prompt) - st.fed)
+                toks[i, :feed] = st.req.prompt[st.fed: st.fed + feed]
+                offs[i] = st.fed
+                feeds[i] = feed
+                bt[i] = self.block_table[i]
+            logits, self.cache = eng._prefill_chunk(
+                eng.params, jnp.asarray(toks), self.cache, jnp.asarray(offs),
+                jnp.asarray(feeds), jnp.asarray(bt), self.page_size)
+            self.chunk_calls += 1
+            fed = int(feeds.sum())
+            self.chunk_fed_tokens += fed
+            self.chunk_slot_tokens += self.n * C
+            if span.is_enabled():
+                span.set_metadata(fed=fed, slot_tokens=self.n * C)
+        bad, sampled = self._sample_rows(logits)
+        with _span("batcher.commit") as span:
+            for i in feeding:
+                st = self.slots[i]
+                if bad[i]:
+                    # unlike the contiguous solo admission prefill, the chunk
+                    # call shares the pool's (n_slots,) row geometry — a
+                    # faulted row is dead for the run exactly like a decode
+                    # fault, so quarantine (and leak the pages)
+                    st.req.error = RequestError(
+                        rid=st.req.rid, stage="prefill", step=st.fed,
+                        reason="non-finite logits from a prefill chunk")
+                    self.done[st.req.rid] = st.req
+                    self.metrics.on_error(st.req.rid)
+                    self._quarantine(i)
+                    continue
+                st.fed += int(feeds[i])
+                self._promote_streamed(i)
+                if st.fed == len(st.req.prompt):
+                    # prompt complete: the chunk's last fed position IS the
+                    # prompt's last position, so its logits seed generation
+                    self.prefills += 1
+                    tok0 = int(sampled[i])
+                    st.tokens.append(tok0)
+                    st.length = len(st.req.prompt)
+                    self.tokens_out += 1
+                    self.metrics.on_first_token(st.req.rid, st.req.tenant)
+                    self.tok[i, 0] = tok0
+                    self._retire_if_done(i)
+            if span.is_enabled():
+                span.set_metadata(compiles=compile_count())
 
     def _promote_streamed(self, slot: int):
         """Register this slot's fully-streamed prompt pages as shared.
@@ -674,47 +691,68 @@ class ContinuousBatcher:
 
         Returns the rids retired by this step (admission / a completing
         chunk can retire n_new=1 requests without a decode).
+
+        The step's phases run in host spans of the profiler, which tile
+        the step in order: ``batcher.admit``; then for the chunk call and
+        for the decode call each, ``batcher.chunk`` / ``batcher.decode``
+        (host preparation and dispatch), ``batcher.sample`` (the finite
+        check and sampling dispatches), ``batcher.wait`` (the blocking
+        reads of their results, nothing else) and ``batcher.commit`` (the
+        per-slot loop and retirement). In a recorded trace the spans carry
+        arguments: ``batcher.admit`` `compile_count` at the step's start,
+        ``batcher.chunk`` the call's ``fed`` and ``slot_tokens``,
+        ``batcher.commit`` `compile_count` after the dispatches.
         """
-        self.metrics.tick()
-        before = set(self.done)
-        self._admit()
-        if self.queue and len(self.dead_slots) >= self.n:
-            # every slot is quarantined: fail the remaining queue with
-            # structured errors rather than spinning forever (run_all
-            # would otherwise loop on a queue no slot can serve)
-            while self.queue:
+        with _span("batcher.admit") as span:
+            self.metrics.tick()
+            before = set(self.done)
+            self._admit()
+            if self.queue and len(self.dead_slots) >= self.n:
+                # every slot is quarantined: fail the remaining queue with
+                # structured errors rather than spinning forever (run_all
+                # would otherwise loop on a queue no slot can serve)
+                while self.queue:
+                    req = self.queue.popleft()
+                    req.error = RequestError(
+                        rid=req.rid, stage="admit", step=0,
+                        reason="all slots quarantined by decode-step faults")
+                    self.done[req.rid] = req
+                    self.metrics.on_error(req.rid)
+            elif (self.paged and self.queue
+                  and all(s is None for s in self.slots)
+                  and self._head_starved()):
+                # page-pool deadlock: nothing is running (so no retire
+                # will ever free a page — quarantine leaks shrank the pool
+                # for good) and the head can never be admitted, even
+                # counting its prefix-cache hits and every evictable cached
+                # page. Fail it with a structured error; smaller queued
+                # requests get their chance next step, in policy order.
                 req = self.queue.popleft()
                 req.error = RequestError(
                     rid=req.rid, stage="admit", step=0,
-                    reason="all slots quarantined by decode-step faults")
+                    reason=f"request needs {self._pages_needed(req)} pages "
+                           f"but only {self.allocator.n_free} remain "
+                           f"allocatable ({self.allocator.n_leaked} leaked "
+                           f"by quarantined slots)")
                 self.done[req.rid] = req
                 self.metrics.on_error(req.rid)
-        elif (self.paged and self.queue
-              and all(s is None for s in self.slots)
-              and self._head_starved()):
-            # page-pool deadlock: nothing is running (so no retire will
-            # ever free a page — quarantine leaks shrank the pool for
-            # good) and the head can never be admitted, even counting its
-            # prefix-cache hits and every evictable cached page. Fail it
-            # with a structured error; smaller queued requests get their
-            # chance next step, in policy order.
-            req = self.queue.popleft()
-            req.error = RequestError(
-                rid=req.rid, stage="admit", step=0,
-                reason=f"request needs {self._pages_needed(req)} pages but "
-                       f"only {self.allocator.n_free} remain allocatable "
-                       f"({self.allocator.n_leaked} leaked by quarantined "
-                       f"slots)")
-            self.done[req.rid] = req
-            self.metrics.on_error(req.rid)
+            if span.is_enabled():
+                span.set_metadata(compiles=compile_count())
         if self.paged:
             self._chunk_step()
+        self._decode_step()
+        return sorted(set(self.done) - before)
+
+    def _decode_step(self):
+        """One decode call over every slot whose prompt is in."""
         # mid-prefill paged slots (fed < prompt) sit this decode out —
         # their rows ride as empty (kv_len 0, block-table row zeroed)
         active = [i for i, s in enumerate(self.slots)
                   if s is not None
                   and (not self.paged or s.fed == len(s.req.prompt))]
-        if active:
+        if not active:
+            return
+        with _span("batcher.decode"):
             eng = self.engine
             # per-slot lengths INCLUDING this step's write; 0 = empty slot
             slot_lens = np.zeros(self.n, np.int32)
@@ -740,9 +778,8 @@ class ContinuousBatcher:
                     jnp.asarray(pad_lens), jnp.int32(self.prefill_len),
                     jnp.asarray(slot_lens))
             self.decode_steps += 1
-            self.rng, sub = jax.random.split(self.rng)
-            bad = eng.nonfinite_rows(logits[:, -1])
-            toks = np.asarray(eng._sample(logits[:, -1], sub))
+        bad, toks = self._sample_rows(logits)
+        with _span("batcher.commit") as span:
             for i in active:
                 st = self.slots[i]
                 if bad[i]:
@@ -766,7 +803,21 @@ class ContinuousBatcher:
                 self.metrics.on_token(st.req.rid, st.req.tenant)
                 self.tok[i, 0] = int(toks[i])
                 self._retire_if_done(i)
-        return sorted(set(self.done) - before)
+            if span.is_enabled():
+                span.set_metadata(compiles=compile_count())
+
+    def _sample_rows(self, logits):
+        """Each row's next token from its last-position logits, and the
+        rows whose logits are not finite: dispatched in ``batcher.sample``,
+        read back in ``batcher.wait`` (the step's blocking reads)."""
+        eng = self.engine
+        with _span("batcher.sample"):
+            last = logits[:, -1]
+            bad = eng.nonfinite_rows(last)
+            self.rng, sub = jax.random.split(self.rng)
+            toks = eng._sample(last, sub)
+        with _span("batcher.wait"):
+            return np.asarray(bad), np.asarray(toks)
 
     def run_all(self) -> dict[int, Request]:
         while self.queue or any(s is not None for s in self.slots):
@@ -782,22 +833,20 @@ class ContinuousBatcher:
         launcher prints it and the ``serve/`` bench rows gate on it.
         """
         s = {
-            "requests_done": len(self.done),
             "prefills": self.prefills,
             "decode_steps": self.decode_steps,
             "decode_tokens": self.decode_tokens,
             "tokens_out": self.tokens_out,
             "model_calls": self.model_calls,
-            "router_policy": self.queue.policy,
-            "router_rejected": self.queue.rejected,
-            "queue_depths": self.queue.depths(),
             "fairness_jain": self.metrics.fairness(self.queue.weights),
         }
         if self.mesh_spec is not None:
             s["mesh"] = self.mesh_spec.describe()
             s["decode_backend"] = self.engine.plan.backend("attention_decode")
         if self.paged:
-            s["chunk_calls"] = self.chunk_calls
+            s.update(chunk_calls=self.chunk_calls,
+                     chunk_fed_tokens=self.chunk_fed_tokens,
+                     chunk_slot_tokens=self.chunk_slot_tokens)
             a = self.allocator
             s.update(pages_allocatable=self.n_pages - 1,
                      pages_in_use=a.pages_in_use, pages_shared=a.n_shared,

@@ -151,8 +151,9 @@ class GenerationEngine:
         return self.plan.explain()
 
     @staticmethod
-    def nonfinite_rows(logits: jax.Array) -> np.ndarray:
-        """(B,) bool host mask: rows whose logits contain NaN/Inf.
+    def nonfinite_rows(logits: jax.Array) -> jax.Array:
+        """(B,) bool mask: rows whose logits contain NaN/Inf, left on the
+        device (the caller reads it back with its other results).
 
         The fail-safe serving check — a device-faulted row (e.g. the
         ``fault_rate`` knob of `repro.hw.noise.NoiseConfig` on the noisy
@@ -164,7 +165,7 @@ class GenerationEngine:
         """
         finite = jnp.isfinite(logits).all(
             axis=tuple(range(1, jnp.ndim(logits))))
-        return np.asarray(~finite)
+        return ~finite
 
     def _sample(self, logits: jax.Array, rng) -> jax.Array:
         if self.temperature <= 0.0:

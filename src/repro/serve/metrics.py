@@ -107,7 +107,6 @@ class ServeMetrics:
         self._submitted: dict[int, int] = {}   # rid -> submit step
         self._last_tok: dict[int, int] = {}    # rid -> last token step
         self.tenant_tokens: dict[str, int] = {}
-        self.tenant_requests: dict[str, int] = {}
         self.rejected = 0
         self.errored = 0
 
@@ -115,9 +114,8 @@ class ServeMetrics:
     def tick(self) -> None:
         self.step += 1
 
-    def on_submit(self, rid: int, tenant: str = "default") -> None:
+    def on_submit(self, rid: int) -> None:
         self._submitted[rid] = self.step
-        self.tenant_requests[tenant] = self.tenant_requests.get(tenant, 0) + 1
 
     def on_reject(self, rid: int) -> None:
         self._submitted.pop(rid, None)
@@ -156,11 +154,9 @@ class ServeMetrics:
         return {
             "steps": self.step,
             "ttft_p50": ttft["p50"], "ttft_p99": ttft["p99"],
-            "ttft_mean": ttft["mean"], "ttft_n": ttft["n"],
-            "tpl_p50": tpl["p50"], "tpl_p99": tpl["p99"],
-            "tpl_mean": tpl["mean"], "tpl_n": tpl["n"],
+            "ttft_n": ttft["n"],
+            "tpl_p50": tpl["p50"], "tpl_p99": tpl["p99"], "tpl_n": tpl["n"],
             "tenant_tokens": dict(sorted(self.tenant_tokens.items())),
-            "tenant_requests": dict(sorted(self.tenant_requests.items())),
             "rejected": self.rejected,
             "errored": self.errored,
         }

@@ -98,10 +98,6 @@ class AdmissionRouter:
     def weight(self, tenant: str) -> float:
         return float(self.weights.get(tenant, 1.0))
 
-    def depths(self) -> dict[str, int]:
-        """Queued requests per tenant (stats/reporting)."""
-        return {t: len(q) for t, q in self._queues.items() if q}
-
     def __len__(self) -> int:
         return sum(len(q) for q in self._queues.values())
 

@@ -62,8 +62,8 @@ def test_ttft_counts_queue_wait_and_tpl_counts_gaps():
     gap in per-token latency."""
     m = ServeMetrics()
     m.tick()                       # step 1
-    m.on_submit(0, "a")
-    m.on_submit(1, "a")            # waits behind rid 0
+    m.on_submit(0)
+    m.on_submit(1)                 # waits behind rid 0
     m.tick()                       # step 2
     m.on_first_token(0, "a")       # TTFT = 2 - 1 = 1
     m.tick()                       # step 3
@@ -77,7 +77,6 @@ def test_ttft_counts_queue_wait_and_tpl_counts_gaps():
     assert sorted(m.ttft.samples) == [1, 3]
     assert sorted(m.tpl.samples) == [1, 2, 3]
     assert m.tenant_tokens == {"a": 5}
-    assert m.tenant_requests == {"a": 2}
     s = m.summary()
     assert s["steps"] == 6 and s["ttft_n"] == 2 and s["tpl_n"] == 3
     assert s["ttft_p99"] == 3 and s["tpl_p50"] == 2

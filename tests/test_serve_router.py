@@ -67,7 +67,7 @@ def test_deque_surface_matches_fifo_semantics():
         r[1]
     assert r[0] is reqs[0] and r[0] is r.popleft()  # peek == pop head
     assert [q.rid for q in _drain(r)] == [1, 2, 3, 4]
-    assert r.depths() == {}
+    assert not r and len(r) == 0 and list(r) == []
 
 
 def test_fifo_is_tenant_blind_arrival_order():
