@@ -11,8 +11,9 @@ Naming convention: ``digital`` is the bf16/f32 baseline; ``raceit_*``
 backends are the paper's analog-faithful paths (``raceit_staged`` = the
 stage-by-stage XLA pipeline, ``raceit_fused`` = the streaming Pallas
 kernel, ``raceit_int`` = exact-ADC int8 crossbar matmul, ``raceit_lut`` =
-Compute-ACAM LUT activations, ``raceit_acam`` = the Fig. 8 softmax
-dataflow). The resident-`QuantizedWeight` form is handled inside the
+Compute-ACAM LUT activations by XLA's gather, ``raceit_lut_kernel`` = the
+same table by the Pallas lane-gather kernel, ``raceit_acam`` = the Fig. 8
+softmax dataflow). The resident-`QuantizedWeight` form is handled inside the
 matmul/lm_head backends (it is a property of the *weight*, not the
 config), always with the plan's ``act_bits`` — never a reconstructed
 default ExecConfig.
@@ -27,6 +28,7 @@ from repro.core.attention import dd_matmul_codes, fused_attention_supported
 from repro.core.ops import LOGIT_FMT
 from repro.core.quant import quantize_tensor
 from repro.core.softmax import acam_softmax
+from repro.kernels.ops import acam_activation
 from repro.models import layers
 from repro.models.layers import NEG_INF, QuantizedWeight
 
@@ -129,12 +131,40 @@ def _activation_digital(plan, x, name=None):
     return jax.nn.silu(x)
 
 
+def _lut_name(plan, name):
+    """The ACAM table an activation name runs (unlisted names map to gelu)."""
+    name = name or plan.model_cfg.activation
+    return name if name in ("gelu", "silu") else "gelu"
+
+
 @register("activation", "raceit_lut")
 def _activation_raceit_lut(plan, x, name=None):
-    """Compute-ACAM LUT activation (unlisted activations map to gelu)."""
-    name = name or plan.model_cfg.activation
-    op = acam_ops.get_op(name if name in ("gelu", "silu") else "gelu")
+    """Compute-ACAM LUT activation, the table applied by XLA's gather."""
+    op = acam_ops.get_op(_lut_name(plan, name))
     return op(x.astype(jnp.float32)).astype(x.dtype)
+
+
+def _lut_kernel_supported(model_cfg, exec_cfg):
+    if exec_cfg.noise is not None:
+        return ("device-noise injection active (ExecConfig.noise); the LUT "
+                "kernel applies the ideal table — noise rides "
+                "raceit_noisy_lut")
+    n_devices = getattr(exec_cfg.mesh, "n_devices", 1)
+    if n_devices > 1:
+        return (f"mesh of {n_devices} devices; the SPMD partitioner does not "
+                f"split a pallas_call, so the sharded hidden activation "
+                f"would be gathered whole — XLA's table lookup partitions")
+    return None
+
+
+@register("activation", "raceit_lut_kernel", supported=_lut_kernel_supported,
+          notes="the same table through the Pallas lane-gather kernel")
+def _activation_raceit_lut_kernel(plan, x, name=None):
+    """Compute-ACAM LUT activation, bit-identical to ``raceit_lut``: one
+    Pallas kernel (`kernels.acam_lut.lane_lookup`) applies the table,
+    where XLA's gather would fetch one element at a time."""
+    y = acam_activation(x.astype(jnp.float32), _lut_name(plan, name))
+    return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

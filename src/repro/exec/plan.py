@@ -223,8 +223,10 @@ def _default_chain(slot: str, exec_cfg: ExecConfig) -> tuple[str, ...]:
     return {
         "matmul": (("raceit_noisy_int", "raceit_int") if noisy
                    else ("raceit_int",)),
+        # the LUT kernel leads, XLA's gather behind it serves what the
+        # kernel's predicate refuses (a multi-device mesh)
         "activation": (("raceit_noisy_lut", "raceit_lut") if noisy
-                       else ("raceit_lut",)),
+                       else ("raceit_lut_kernel", "raceit_lut")),
         "softmax": (("raceit_noisy_acam", "raceit_acam") if noisy
                     else ("raceit_acam",)),
         "dd_matmul": (("acam", "int") if exec_cfg.matmul_fidelity == "acam"

@@ -15,6 +15,7 @@ the softmax tables).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from jax.experimental import pallas as pl
 from .runtime import resolve_interpret
 
 DEFAULT_BLOCK_ROWS = 256
+BLOCK_COLS = 1024  # a power-of-two multiple of LANES
 LANES = 128
 TABLE_SHAPE = (2, LANES)  # a 256-entry table as two 128-lane rows
 
@@ -70,7 +72,11 @@ def acam_lut_2d(x: jax.Array, lut: jax.Array, bias: int = 128,
     """Apply an ACAM LUT to a 2-D int tensor of shape (R, C).
 
     x: int8/int32 codes in [-2^(n-1), 2^(n-1)); lut: (256,) output codes.
-    Rows/cols are padded to tile boundaries and cropped after.
+    Rows are padded to the row block and columns to whole 128-lane chunks,
+    and cropped after; a column block is the largest power-of-two multiple
+    of 128 lanes, up to `BLOCK_COLS`, that divides the padded width, so
+    a block stays a few MiB of VMEM however wide the rows are (an FFN
+    hidden layer is 8192 wide).
     """
     interpret = resolve_interpret(interpret)
     R, C = x.shape
@@ -79,16 +85,17 @@ def acam_lut_2d(x: jax.Array, lut: jax.Array, bias: int = 128,
     pad_c = (-C) % LANES
     xp = jnp.pad(x, ((0, pad_r), (0, pad_c)))
     Rp, Cp = xp.shape
+    bc = LANES * math.gcd(Cp // LANES, BLOCK_COLS // LANES)
 
     out = pl.pallas_call(
         functools.partial(_lut_kernel, bias=bias),
         out_shape=jax.ShapeDtypeStruct((Rp, Cp), jnp.int32),
         in_specs=[
-            pl.BlockSpec((br, Cp), lambda i: (i, 0)),
-            pl.BlockSpec(TABLE_SHAPE, lambda i: (0, 0)),  # table in VMEM
+            pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+            pl.BlockSpec(TABLE_SHAPE, lambda i, j: (0, 0)),  # table in VMEM
         ],
-        out_specs=pl.BlockSpec((br, Cp), lambda i: (i, 0)),
-        grid=(Rp // br,),
+        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+        grid=(Rp // br, Cp // bc),
         interpret=interpret,
     )(xp, lane_table(lut.astype(jnp.int32)))
     return out[:R, :C]

@@ -64,7 +64,7 @@ def test_every_combo_resolves_deterministically(mode, softmax_mode, fidelity,
         assert chosen["dd_matmul"] == "int"
     else:
         assert chosen["matmul"] == "raceit_int"
-        assert chosen["activation"] == "raceit_lut"
+        assert chosen["activation"] == "raceit_lut_kernel"
         assert chosen["softmax"] == "raceit_acam"
         assert chosen["dd_matmul"] == fidelity
         want_attn = ("raceit_fused" if fused and fidelity == "int"
@@ -303,3 +303,129 @@ def test_model_forward_same_via_config_and_plan(key, mode):
     a = m1.forward(params, batch, use_remat=False)
     b = m2.forward(params, batch, use_remat=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# activation: the Pallas LUT kernel against XLA's gather
+# ---------------------------------------------------------------------------
+
+GATHER = ExecConfig(mode="raceit").with_ops(activation="raceit_lut")
+
+
+def _activation_inputs(rng, shape):
+    """Every input code's value first (the 1-2-5 range [-4, 3.97]), then
+    random floats inside the range, then beyond it, repeated to fill."""
+    from repro.core.ops import GELU_FMT
+    codes = GELU_FMT.decode(np.arange(GELU_FMT.code_min,
+                                      GELU_FMT.code_max + 1))
+    inside = rng.uniform(GELU_FMT.min_value, GELU_FMT.max_value, 256)
+    beyond = rng.normal(0, 8, 256)
+    pool = np.concatenate([codes, inside, beyond]).astype(np.float32)
+    return jnp.asarray(np.resize(pool, shape))
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (7, 130), (3, 5, 64), (16, 8192)])
+@pytest.mark.parametrize("name", ["silu", "gelu"])
+def test_lut_kernel_activation_bit_identical_to_gather(rng, name, shape):
+    cfg = _cfg()
+    kernel = resolve_plan(cfg, ExecConfig(mode="raceit"))
+    gather = resolve_plan(cfg, GATHER)
+    assert kernel.backend("activation") == "raceit_lut_kernel"
+    assert gather.backend("activation") == "raceit_lut"
+    x = _activation_inputs(rng, shape)
+    got = kernel.activation(x, name)
+    want = gather.activation(x, name)
+    assert got.shape == want.shape == shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_lut_kernel_keeps_name_mapping_and_dtype(rng):
+    """Unlisted activations map to gelu, and bf16 comes back as bf16."""
+    cfg = _cfg(activation="relu")
+    x = _activation_inputs(rng, (4, 256)).astype(jnp.bfloat16)
+    got = resolve_plan(cfg, ExecConfig(mode="raceit")).activation(x)
+    want = resolve_plan(cfg, GATHER).activation(x, "gelu")
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_lut_kernel_resolution_records_each_degrade():
+    from repro.dist import MeshSpec
+    from repro.hw.noise import NoiseConfig
+    cfg = _cfg()
+
+    clean = resolve_plan(cfg, ExecConfig(mode="raceit"))
+    assert clean.op("activation").backend == "raceit_lut_kernel"
+    assert clean.op("activation").reason is None
+    assert "raceit_lut_kernel" in clean.explain()
+
+    # the noisy chain is unchanged; a pinned kernel degrades into it
+    noise = NoiseConfig.preset("nominal")
+    noisy = resolve_plan(cfg, ExecConfig(mode="raceit", noise=noise))
+    assert noisy.backend("activation") == "raceit_noisy_lut"
+    pinned = resolve_plan(cfg, ExecConfig(mode="raceit", noise=noise)
+                          .with_ops(activation="raceit_lut_kernel"))
+    op = pinned.op("activation")
+    assert op.backend == "raceit_noisy_lut"
+    assert "noise" in op.reason and op.reason in pinned.explain()
+
+    mesh = resolve_plan(cfg, ExecConfig(mode="raceit",
+                                        mesh=MeshSpec.parse("model=2")))
+    op = mesh.op("activation")
+    assert (op.backend, op.requested) == ("raceit_lut", "raceit_lut_kernel")
+    assert "mesh of 2 devices" in op.reason and op.reason in mesh.explain()
+    assert any(d.slot == "activation" and d.requested == "raceit_lut_kernel"
+               and d.chosen == "raceit_lut" for d in mesh.degrades)
+    one = resolve_plan(cfg, ExecConfig(mode="raceit",
+                                       mesh=MeshSpec.parse("model=1")))
+    assert one.backend("activation") == "raceit_lut_kernel"
+
+    digital = resolve_plan(cfg, ExecConfig(mode="digital"))
+    assert digital.backend("activation") == "digital"
+    assert not any(d.slot == "activation" for d in digital.degrades)
+
+
+def _q8_ffn_params(rng, cfg):
+    from repro.models.model import quantize_model_params
+    shapes = {"w1": (cfg.d_model, cfg.d_ff), "w3": (cfg.d_model, cfg.d_ff),
+              "w2": (cfg.d_ff, cfg.d_model)}
+    return quantize_model_params(
+        {k: jnp.asarray(rng.normal(0, 0.1, s), jnp.float32)
+         for k, s in shapes.items()})
+
+
+def test_ffn_same_with_lut_kernel_and_gather(rng):
+    cfg = _cfg(glu=True, activation="silu", d_ff=256)
+    p = _q8_ffn_params(rng, cfg)
+    assert isinstance(p["w1"], layers.QuantizedWeight)
+    x = jnp.asarray(rng.normal(0, 1, (2, 8, cfg.d_model)), jnp.float32)
+    got = layers.ffn(p, x, cfg, ExecConfig(mode="raceit"))
+    want = layers.ffn(p, x, cfg, GATHER)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_paged_serving_same_tokens_with_lut_kernel_and_gather(key):
+    from conftest import tiny_config
+    from repro.configs import get_config
+    from repro.models import Model
+    from repro.models.model import quantize_model_params
+    from repro.serve import ContinuousBatcher, GenerationEngine, Request
+    cfg = tiny_config(get_config("olmo-1b"))
+    params = quantize_model_params(Model(cfg, ExecConfig()).init(key))
+    rng = np.random.default_rng(5)
+    reqs = [(i, rng.integers(0, cfg.vocab_size, n).astype(np.int32), m)
+            for i, (n, m) in enumerate([(9, 5), (4, 3), (12, 4)])]
+    served = {}
+    for ec in (ExecConfig.serving(),
+               ExecConfig.serving().with_ops(activation="raceit_lut")):
+        eng = GenerationEngine(cfg, params, exec_cfg=ec, max_len=64)
+        cb = ContinuousBatcher(eng, n_slots=2, paged=True, page_size=8)
+        for rid, prompt, n_new in reqs:
+            cb.submit(Request(rid, prompt, n_new=n_new))
+        served[eng.plan.backend("activation")] = {
+            rid: r.result for rid, r in cb.run_all().items()}
+    assert sorted(served) == ["raceit_lut", "raceit_lut_kernel"]
+    for rid, _, n_new in reqs:
+        got = served["raceit_lut_kernel"][rid]
+        assert got.shape == (n_new,)
+        np.testing.assert_array_equal(got, served["raceit_lut"][rid])

@@ -1,13 +1,13 @@
 """Mosaic compile checks: the serving kernels compile for a TPU v5e.
 
-Each test lowers one fused attention entry point with ``interpret=False``
-at real model widths and compiles it for a *described* v5e chip (the TPU
-compiler is installed; no chip is attached or touched), then asserts that
-the executable holds the Pallas kernel (``tpu_custom_call``). This catches
-what interpret mode cannot: in-kernel vector gathers, int32 MXU operands,
-scalars outside SMEM, block shapes off the (8, 128) tiling, and VMEM
-overruns. Nothing here runs a kernel; numerics are the interpret-mode
-suites' job.
+Each test lowers one fused attention entry point, or one model layer,
+with ``interpret=False`` at real model widths and compiles it for a
+*described* v5e chip (the TPU compiler is installed; no chip is attached
+or touched), then asserts that the executable holds the Pallas kernel
+(``tpu_custom_call``). This catches what interpret mode cannot: in-kernel
+vector gathers, int32 MXU operands, scalars outside SMEM, block shapes off
+the (8, 128) tiling, and VMEM overruns. Nothing here runs a kernel;
+numerics are the interpret-mode suites' job.
 
 The topology is described inside a fixture, never at import time, so only
 the worker that runs this file loads the TPU compiler library.
@@ -18,10 +18,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.base import ExecConfig
+from repro.exec import resolve_plan
 from repro.kernels import ops
+from repro.models import layers
 
 GPT2 = get_config("gpt2-large")            # 20 heads x 64, MHA
 STARCODER2 = get_config("starcoder2-15b")  # 48 heads, 4 KV heads, x 128
+OLMO = get_config("olmo-1b")               # d 2048, SwiGLU d_ff 8192
 PAGE, SLOTS, MAX_PAGES = 64, 4, 2           # the serving launcher defaults
 N_PAGES = 1 + SLOTS * MAX_PAGES
 
@@ -83,8 +87,9 @@ D_MODEL = GPT2.d_model
 @pytest.mark.parametrize("name", ["acam_lut", "acam_softmax_codes",
                                   "acam_mvm"])
 def test_offpath_kernel_compiles(one_chip, name):
-    """The ACAM kernels the serving path does not call (the staged
-    activation, softmax and crossbar dataflow) compile too."""
+    """The ACAM kernels outside the fused attention compile too: the LUT,
+    which applies the serving path's activation (here on int8 codes), and
+    the softmax and crossbar dataflow, which it does not call."""
     from repro.core.crossbar import CrossbarConfig
     fn, shapes = {
         "acam_lut": (lambda x, t: ops.acam_lut(x, t, interpret=False),
@@ -107,3 +112,42 @@ def test_gqa_paged_decode_compiles(one_chip):
              ((SLOTS, H, 1, hd), jnp.float32), _pool(STARCODER2),
              _pool(STARCODER2), ((SLOTS,), jnp.int32),
              ((SLOTS, MAX_PAGES), jnp.int32))
+
+
+@pytest.fixture
+def mosaic_kernels(monkeypatch):
+    """Model code resolves its kernels' interpret flag from the backend,
+    which is the CPU here: make it compile them for Mosaic. jit's traces
+    are dropped on both sides, so none traced in interpret mode is reused
+    here and none traced for Mosaic is left to a later test."""
+    from repro.kernels import runtime
+    jax.clear_caches()
+    monkeypatch.setattr(runtime, "default_interpret", lambda: False)
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("rows", [1, 64])
+def test_raceit_ffn_applies_activation_with_lut_kernel(one_chip,
+                                                       mosaic_kernels, rows):
+    """The raceit_q8 FFN at OLMo-1B widths, for 16 slots of one decode row
+    and of one 64-row prefill chunk: the activation's table is the Pallas
+    kernel, and no XLA gather is left in the program."""
+    cfg = OLMO.replace(param_dtype="float32", compute_dtype="float32")
+    plan = resolve_plan(cfg, ExecConfig.serving())
+    assert plan.backend("activation") == "raceit_lut_kernel"
+
+    def weight(k, n):
+        return layers.QuantizedWeight(
+            jax.ShapeDtypeStruct((k, n), jnp.int8, sharding=one_chip),
+            jax.ShapeDtypeStruct((1, n), jnp.float32, sharding=one_chip),
+            (n,))
+
+    d, f = cfg.d_model, cfg.d_ff
+    p = {"w1": weight(d, f), "w3": weight(d, f), "w2": weight(f, d)}
+    x = jax.ShapeDtypeStruct((16, rows, d), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda p, x: layers.ffn(p, x, cfg, plan)).lower(
+        p, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "gather(" not in text
