@@ -4,11 +4,16 @@ mix, both found by name, served through the program's own engine.
     cell "gpt2-large.chat" = bench/configs/gpt2-large.json
                            x bench/traffic/chat.json
     metric "x"             = bench/metrics/x.py, a ``read(ctx)`` function
+    "module": "m" in a configuration's reference block
+                           = bench/refs/m.py (default: the dense
+                             decoder's module, bench/model_ref)
 
-Nothing here names a configuration, a mix or a metric: a new one is a new
-file. The serving loop is the benchmark's own: it submits each request at
-its due time (open loop) or as the closed loop asks (a backlog kept
-full, or a session's next ask when its answer returns), calls
+Nothing here names a configuration, a mix, a metric or an architecture: a
+new one is a new file. A configuration's reference module makes its
+weights, runs its reference and counts its operations. The serving loop
+is the benchmark's own: it submits each request at its due time (open
+loop) or as the closed loop asks (a backlog kept full, or a session's
+next ask when its answer returns), calls
 `ContinuousBatcher.step()`, and stamps the tokens a step produced on the
 host clock when the step returns (the step has read the sampled tokens
 back, so their device work is done).
@@ -17,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -63,14 +69,44 @@ def load_traffic(name: str, bench_dir: Path = BENCH_DIR) -> dict:
     return load_json(bench_dir / "traffic" / f"{name}.json")
 
 
-def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
-    """The ``read(ctx)`` function of metric ``name``, from its own file."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+def _load_module(prefix: str, path: Path):
+    name = path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(f"{prefix}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
+    """The ``read(ctx)`` function of metric ``name``, from its own file."""
+    return _load_module("bench_metric",
+                        bench_dir / "metrics" / f"{name}.py").read
+
+
+@functools.lru_cache(maxsize=None)
+def _load_reference(path: Path):
+    # once per file, so that its jitted functions keep their traces
+    return _load_module("bench_ref", path)
+
+
+def reference_module(cfg_file: dict, bench_dir: Path = BENCH_DIR):
+    """The configuration's reference module, whose contract the docstring
+    of `bench/model_ref` states: ``bench/refs/<module>.py`` where its
+    reference block names one, else that dense default."""
+    name = cfg_file["reference"].get("module")
+    if name is None:
+        return model_ref
+    return _load_reference((bench_dir / "refs" / f"{name}.py").resolve())
+
+
+def freeze(x):
+    """JSON made hashable: a list becomes a tuple, a dict its sorted
+    items, at every depth; a static argument of a jitted function."""
+    if isinstance(x, dict):
+        return tuple(sorted((k, freeze(v)) for k, v in x.items()))
+    if isinstance(x, list):
+        return tuple(freeze(v) for v in x)
+    return x
 
 
 def cell_metrics(bm: dict, cell: str, trace: bool) -> list[dict]:
@@ -92,28 +128,37 @@ def run_key_int(seed: int) -> int:
 
 
 # ---------------------------------------------------------------- system
-def make_params(cfg_file: dict, seed: int):
-    """The served weights, made on the device in one jitted call from the
-    seed (and quantized inside it when the mode serves int8)."""
+def make_params(cfg_file: dict, seed: int, bench_dir: Path = BENCH_DIR):
+    """The served weights, made on the device by the reference module from
+    the seed (and quantized by the program when the mode serves int8)."""
     import jax
     from repro.models.model import quantize_model_params
-    arch, mode = cfg_file["reference"], cfg_file["serving"]["mode"]
-    make = lambda k: model_ref.make_weights(k, arch)
-    made = ((lambda k: quantize_model_params(make(k)))
-            if mode == "raceit_q8" else make)
-    return jax.jit(made)(jax.random.PRNGKey(run_key_int(seed)))
+    quantize = (quantize_model_params
+                if cfg_file["serving"]["mode"] == "raceit_q8"
+                else (lambda tree: tree))
+    return reference_module(cfg_file, bench_dir).served_params(
+        jax.random.PRNGKey(run_key_int(seed)), cfg_file["reference"],
+        quantize)
+
+
+def program_config(cfg_file: dict):
+    """The program's model configuration with the file's overrides; JSON
+    lists become the tuples that layer patterns are."""
+    from repro.configs import get_config
+    prog = cfg_file["program"]
+    return get_config(prog["name"]).replace(**{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in prog["overrides"].items()})
 
 
 def build_engine(cfg_file: dict, params):
-    from repro.configs import get_config
     from repro.configs.base import ExecConfig
     from repro.serve import GenerationEngine
-    prog, srv = cfg_file["program"], cfg_file["serving"]
-    cfg = get_config(prog["name"]).replace(**prog["overrides"])
+    srv = cfg_file["serving"]
     exec_cfg = ExecConfig.serving(
         mode="raceit" if srv["mode"].startswith("raceit") else "digital")
-    return GenerationEngine(cfg, params, exec_cfg=exec_cfg,
-                            max_len=srv["max_len"])
+    return GenerationEngine(program_config(cfg_file), params,
+                            exec_cfg=exec_cfg, max_len=srv["max_len"])
 
 
 def new_batcher(cfg_file: dict, eng):
@@ -125,10 +170,10 @@ def new_batcher(cfg_file: dict, eng):
                              prefix_cache=srv["prefix_cache"])
 
 
-def build_system(cfg_file: dict, seed: int):
+def build_system(cfg_file: dict, seed: int, bench_dir: Path = BENCH_DIR):
     """The program objects the window drives: the seed's weights, the
     engine, and the paged continuous batcher."""
-    eng = build_engine(cfg_file, make_params(cfg_file, seed))
+    eng = build_engine(cfg_file, make_params(cfg_file, seed, bench_dir))
     return eng, new_batcher(cfg_file, eng)
 
 
@@ -145,10 +190,13 @@ def warm_compile(batcher, vocab: int, seed: int) -> None:
 
 
 def counters(batcher) -> dict:
+    """Every whole-number counter of the batcher's summary, for readers and
+    reference modules; those the harness reads are 0 where it keeps none."""
     s = batcher.summary()
-    return {k: s.get(k, 0) for k in (
-        "decode_steps", "decode_tokens", "prefix_hit_pages",
-        "prefix_miss_pages", "prefix_evictions")}
+    out = {k: 0 for k in ("decode_steps", "decode_tokens", "prefix_hit_pages",
+                          "prefix_miss_pages", "prefix_evictions")}
+    out.update((k, v) for k, v in s.items() if type(v) is int)
+    return out
 
 
 # ---------------------------------------------------------------- loop
@@ -439,7 +487,8 @@ def pick_sample(win: Window, seed: int,
     return sample
 
 
-def reference_gaps(cfg_file: dict, seed: int, items, control_bits=None):
+def reference_gaps(cfg_file: dict, seed: int, items, control_bits=None,
+                   bench_dir: Path = BENCH_DIR):
     """For each (prompt, served tokens): the gap by which each served
     token's reference logit lies below the reference's best, and with
     ``control_bits`` the same for the tokens that the lower-precision
@@ -447,11 +496,10 @@ def reference_gaps(cfg_file: dict, seed: int, items, control_bits=None):
     per-token arrays. Weights are made anew from the seed."""
     import jax
     import jax.numpy as jnp
-    arch = cfg_file["reference"]
-    key = jax.random.PRNGKey(run_key_int(seed))
-    w = jax.jit(lambda k: model_ref.make_weights(k, arch))(key)
+    ref, arch = reference_module(cfg_file, bench_dir), cfg_file["reference"]
+    w = ref.reference_weights(jax.random.PRNGKey(run_key_int(seed)), arch)
     S = cfg_file["serving"]["max_len"]
-    static = tuple(sorted(arch.items()))
+    static = freeze(arch)
     gaps, ctl = [], []
     for prompt, served in items:
         P, n = len(prompt), len(served)
@@ -462,9 +510,9 @@ def reference_gaps(cfg_file: dict, seed: int, items, control_bits=None):
         tgt[P - 1: P - 1 + n] = served
         valid = np.zeros(S, bool)
         valid[P - 1: P - 1 + n] = True
-        g, c = model_ref.gap_readings(w, jnp.asarray(toks), jnp.asarray(tgt),
-                                      jnp.asarray(valid), static,
-                                      control_bits=control_bits)
+        g, c = ref.gap_readings(w, jnp.asarray(toks), jnp.asarray(tgt),
+                                jnp.asarray(valid), static,
+                                control_bits=control_bits)
         gaps.append(np.asarray(g)[valid])
         if c is not None:
             ctl.append(np.asarray(c)[valid])
@@ -488,7 +536,8 @@ def gap_numbers(per_token: list) -> dict:
 
 def check_outputs(cfg_file: dict, seed: int, win: Window, sample,
                   shares_prefix: bool = False,
-                  control_bits: Optional[int] = None) -> dict:
+                  control_bits: Optional[int] = None,
+                  bench_dir: Path = BENCH_DIR) -> dict:
     """The numbers compared, each beside its limit. A limit of None marks
     a number that is reported and not compared. With ``control_bits`` the
     control stands in the program's place: the gaps read are those of the
@@ -498,8 +547,8 @@ def check_outputs(cfg_file: dict, seed: int, win: Window, sample,
                      and r.n_tok != r.spec.n_new)
     failed = sum(1 for r in win.records.values() if r.error is not None)
     items = [(r.spec.prompt, r.served) for r in sample]
-    gaps, ctl = (reference_gaps(cfg_file, seed, items, control_bits)
-                 if items else ([], []))
+    gaps, ctl = (reference_gaps(cfg_file, seed, items, control_bits,
+                                bench_dir) if items else ([], []))
     if control_bits is not None:
         gaps = ctl
     out = {"sampled_requests": {"value": len(sample), "limit": 1,
@@ -535,6 +584,7 @@ class Run:
     trace: object = None          # trace_reduce.Reduced, --trace 1 only
     extra: dict = dataclasses.field(default_factory=dict)
     sample: list = dataclasses.field(default_factory=list)  # checked
+    ref: object = None            # the configuration's reference module
 
 
 def run_cell(cell: dict, seed: int, seconds: float, t_process: float,
@@ -546,7 +596,7 @@ def run_cell(cell: dict, seed: int, seconds: float, t_process: float,
     import jax
     cfg_file = load_config(cell["config"], bench_dir)
     mix = load_traffic(cell["traffic"], bench_dir)
-    eng, batcher = build_system(cfg_file, seed)
+    eng, batcher = build_system(cfg_file, seed, bench_dir)
     vocab = cfg_file["reference"]["vocab_size"]
     warm_compile(batcher, vocab, seed)
     traffic = Traffic(mix, seed, seconds, vocab, batcher.n)
@@ -570,11 +620,12 @@ def run_cell(cell: dict, seed: int, seconds: float, t_process: float,
     del eng, batcher
     gc.collect()
     checks = check_outputs(cfg_file, seed, win, sample,
-                           traffic.shares_prefix)
+                           traffic.shares_prefix, bench_dir=bench_dir)
     return Run(cell, cfg_file, mix, seed, win, setup_s, dev,
                extra={"kv_bytes": kv_bytes,
                       "shares_prefix": traffic.shares_prefix},
-               sample=sample), checks
+               sample=sample,
+               ref=reference_module(cfg_file, bench_dir)), checks
 
 
 def read_metrics(run: Run, metrics: list[dict],

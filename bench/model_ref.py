@@ -16,6 +16,30 @@ The control of the output check is this same reference with every weight
 matmul computed at ``bits`` (symmetric, per-column weight scales and one
 activation scale per sequence): the next precision below the int8 the
 serving mode states.
+
+This module is the default reference module. A configuration whose
+``reference`` block names ``"module": "<name>"`` brings its own, the file
+``bench/refs/<name>.py``, and the harness and the metric readers reach
+the architecture only through these five functions of it:
+
+- ``served_params(key, arch, quantize)``: the served tree on the device,
+  ``quantize`` (the program's own int8 conversion, or the identity)
+  applied to the weights made from ``key``.
+- ``reference_weights(key, arch)``: what ``gap_readings`` takes as ``w``,
+  made from the same ``key``: the same weights in float32, or anything
+  from which the reference makes them (the key itself, layer by layer).
+- ``gap_readings(w, tokens, targets, valid, arch, control_bits)``: below.
+  Here ``arch`` is the block frozen into nested tuples (a dict becomes
+  its sorted items), so that it can be a static argument.
+- ``attn_cost(arch, kind, rows, kv_bytes)``: ``(ops, bytes)`` of the
+  attention of all layers in one ``"decode"`` call (``rows``: live keys
+  per row) or ``"chunk"`` call (``rows``: ``(offset, feed)`` per row).
+- ``step_ops(arch, chunk_rows, decode_lens, counters)``: the model
+  operations of one call; ``counters`` holds the window's program
+  counters (each one's change over the window), for a model whose work
+  depends on what the program chose, as a routed one's does.
+
+Elsewhere ``arch`` is the block as loaded from JSON.
 """
 from __future__ import annotations
 
@@ -24,6 +48,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from . import roofline
 
 HIGHEST = jax.lax.Precision.HIGHEST
 LN_EPS = 1e-5  # GPT-2 layer_norm_epsilon, OLMo layer_norm eps
@@ -65,6 +91,33 @@ def make_weights(key, a: dict) -> dict:
               "bias": _normal(next(ks), (d,), 0.02)} if affine else {})
     return {"embed": embed, "final_norm": final,
             "blocks": {"scan": [layer], "tail": []}}
+
+
+def served_params(key, arch: dict, quantize):
+    """The served tree, made and quantized in one jitted call."""
+    return jax.jit(lambda k: quantize(make_weights(k, arch)))(key)
+
+
+def reference_weights(key, arch: dict) -> dict:
+    """The whole f32 tree, made anew from the key."""
+    return jax.jit(lambda k: make_weights(k, arch))(key)
+
+
+def attn_cost(arch: dict, kind: str, rows, kv_bytes: int) -> tuple[int, int]:
+    """``n_layers`` times one layer's attention cost (`bench/roofline.py`)."""
+    h, hd = arch["n_heads"], arch["head_dim"]
+    kv, L = arch.get("n_kv_heads", h), arch["n_layers"]
+    if kind == "decode":
+        ops, nbytes = roofline.attn_decode_cost(rows, h, kv, hd, kv_bytes)
+    else:
+        ops, nbytes = roofline.attn_chunk_cost(
+            [o for o, _ in rows], [f for _, f in rows], h, kv, hd, kv_bytes)
+    return L * ops, L * nbytes
+
+
+def step_ops(arch: dict, chunk_rows, decode_lens, counters: dict) -> int:
+    """A dense model's work does not depend on ``counters``."""
+    return roofline.step_ops(arch, chunk_rows, decode_lens)
 
 
 def _layernorm(x, p):

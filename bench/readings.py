@@ -47,7 +47,8 @@ def _calls(run: Run, kind: str) -> list:
 def attn_roofline_pct(run: Run, kind: str) -> Optional[float]:
     """The attention kernel's share of its roofline, in the decode or the
     chunk executable: the least time the chip needs for the attention the
-    traced calls asked for, over the kernel time the trace shows."""
+    traced calls asked for (as the reference module counts it), over the
+    kernel time the trace shows."""
     if run.trace is None:
         return None
     kernel = run.trace.kernel_s.get(kind, [])
@@ -56,26 +57,19 @@ def attn_roofline_pct(run: Run, kind: str) -> Optional[float]:
     spent = sum(kernel[len(kernel) - n:])
     if n == 0 or spent <= 0.0:
         return None
-    a = run.cfg_file["reference"]
-    h, kv, hd = a["n_heads"], a.get("n_kv_heads", a["n_heads"]), a["head_dim"]
-    kvb = run.extra["kv_bytes"]
+    a, kvb = run.cfg_file["reference"], run.extra["kv_bytes"]
     pk = roofline.peaks(run.device["kind"])
     need = 0.0
     for _, _, rows in calls[len(calls) - n:]:
-        if kind == "decode":
-            ops, nbytes = roofline.attn_decode_cost(rows, h, kv, hd, kvb)
-        else:
-            ops, nbytes = roofline.attn_chunk_cost(
-                [o for o, _ in rows], [f for _, f in rows], h, kv, hd, kvb)
-        need += roofline.bound_seconds(a["n_layers"] * ops,
-                                       a["n_layers"] * nbytes, pk)[0]
+        ops, nbytes = run.ref.attn_cost(a, kind, rows, kvb)
+        need += roofline.bound_seconds(ops, nbytes, pk)[0]
     return 100.0 * need / spent
 
 
 def step_mfu_pct(run: Run) -> Optional[float]:
     """Model operations of every chunk and decode call in the traced part
-    (useful rows only) over the wall time of the steps that made them,
-    times the chip's int8 peak."""
+    (useful rows only, as the reference module counts them) over the wall
+    time of the steps that made them, times the chip's int8 peak."""
     win = run.window
     if win.trace is None or not win.calls:
         return None
@@ -84,12 +78,13 @@ def step_mfu_pct(run: Run) -> Optional[float]:
     if wall <= 0.0:
         return None
     a = run.cfg_file["reference"]
+    seen = {k: counter_delta(run, k) for k in win.counters_after}
     ops = 0
     for kind, t, rows in win.calls:
         if kind == "chunk":
-            ops += roofline.step_ops(a, rows, [])
+            ops += run.ref.step_ops(a, rows, [], seen)
         else:
-            ops += roofline.step_ops(a, [], rows)
+            ops += run.ref.step_ops(a, [], rows, seen)
     pk = roofline.peaks(run.device["kind"])
     return 100.0 * ops / (wall * pk["int8_ops_per_s"])
 
