@@ -29,15 +29,36 @@ class ModelConfig:
     head_pad_to: Optional[int] = None
 
     # --- layer heterogeneity (cycled over layer index) ---
-    mixer_pattern: tuple = ("attn",)       # "attn" | "attn_local" | "mamba"
+    mixer_pattern: tuple = ("attn",)       # "attn"|"attn_local"|"mamba"|"mla"
     ffn_pattern: tuple = ("dense",)        # "dense" | "moe" | "none"
     window: int = 1024                     # local-attention window
+    # leading layers with a dense FFN of width d_ff ahead of the scanned
+    # pattern (deepseek-v3's first_k_dense_replace)
+    first_k_dense: int = 0
+
+    # --- latent attention ("mla" mixer, deepseek-v2/v3): per-token cache of
+    # one kv_lora_rank latent plus one shared qk_rope_head_dim RoPE key ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0                     # routed experts the router scores
     top_k: int = 1
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25          # mesh dispatch only (models/moe.py)
     expert_parallel: bool = False          # EP over "model" (else TP-in-expert)
+    moe_d_ff: Optional[int] = None         # expert width; default d_ff
+    n_shared_experts: int = 0              # one SwiGLU of n x moe_d_ff, every row
+    router_scoring: str = "softmax"        # "softmax" | "sigmoid"
+    # sigmoid routing picks the top_k of scores + a per-expert correction
+    # bias (the "router_bias" leaf; noaux_tc) and gates by the unbiased
+    # scores; either scoring normalizes the chosen gates to sum 1 and
+    # scales them by routed_scaling_factor
+    routed_scaling_factor: float = 1.0
+    # global ids of the experts whose weights this device holds (one EP
+    # rank's share); None = all n_experts
+    experts_held: Optional[tuple] = None
 
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
@@ -51,6 +72,7 @@ class ModelConfig:
     activation: str = "silu"               # "silu" | "gelu"
     glu: bool = True                       # gated FFN (SwiGLU/GeGLU)
     norm: str = "rmsnorm"                  # "rmsnorm"|"layernorm"|"np_layernorm"
+    norm_eps: float = 1e-6
     qkv_bias: bool = False
     pos_emb: str = "rope"                  # "rope"|"mrope"|"learned"|"sinusoidal"|"none"
     rope_theta: float = 1e6
@@ -91,9 +113,27 @@ class ModelConfig:
         return math.lcm(len(self.mixer_pattern), len(self.ffn_pattern))
 
     def layer_spec(self, i: int) -> tuple:
-        """(mixer, ffn) kind of layer i."""
-        return (self.mixer_pattern[i % len(self.mixer_pattern)],
-                self.ffn_pattern[i % len(self.ffn_pattern)])
+        """(mixer, ffn) kind of layer i; the first ``first_k_dense``
+        layers take a dense FFN whatever the pattern says."""
+        ffn = ("dense" if i < self.first_k_dense
+               else self.ffn_pattern[i % len(self.ffn_pattern)])
+        return (self.mixer_pattern[i % len(self.mixer_pattern)], ffn)
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff if self.moe_d_ff is not None else self.d_ff
+
+    @property
+    def held_experts(self) -> tuple:
+        """Global ids of the experts this device holds, in stack order."""
+        if self.experts_held is None:
+            return tuple(range(self.n_experts))
+        return tuple(self.experts_held)
+
+    @property
+    def mla_head_dim(self) -> int:
+        """Width of one latent cache row: the latent plus the RoPE key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def d_inner(self) -> int:

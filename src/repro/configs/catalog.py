@@ -2,7 +2,7 @@
 from . import (  # noqa: F401
     llama4_scout_17b_a16e, mixtral_8x22b, command_r_35b, gemma3_4b,
     starcoder2_15b, olmo_1b, mamba2_130m, jamba_v0_1_52b, qwen2_vl_2b,
-    whisper_tiny, bert_base, bert_large, gpt2_large,
+    whisper_tiny, bert_base, bert_large, gpt2_large, moonlight_16b_a3b,
 )
 
 ASSIGNED = [

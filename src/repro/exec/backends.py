@@ -461,14 +461,11 @@ def _decode_raceit_gqa_paged(plan, q, k, v, *, kv_len, scale,
     if block_table is None:
         return layers._raceit_gqa_decode(q, k, v, kv_len, scale, plan,
                                          pad_valid=pad_valid)
-    # chunked-prefill steps (Sq > 1) ride the flat paged entry — same
-    # rationale as _raceit_gqa_decode's Sq>1 delegate: the GQA grid's row
-    # dimension carries the rep sharing queries, which a chunk needs for
-    # its Sq positions; bit-identical either way
+    # chunked-prefill steps (Sq > 1) put rep x Sq queries on the GQA
+    # grid's rows, so the pool is not repeated to H for them either
     return layers._raceit_paged_decode(q, k, v, kv_len, scale, plan,
                                        pad_valid=pad_valid,
-                                       block_table=block_table,
-                                       gqa=q.shape[1] == 1)
+                                       block_table=block_table, gqa=True)
 
 
 # ---------------------------------------------------------------------------

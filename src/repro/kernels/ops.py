@@ -335,12 +335,12 @@ def raceit_attention_decode_paged(
 @partial(jax.jit, static_argnames=("softmax_mode", "fold_scale",
                                    "block_k", "block_g", "interpret"))
 def raceit_attention_decode_gqa_paged(
-    q: jax.Array,       # (B, H, 1, D) float — the new token's queries
+    q: jax.Array,       # (B, H, Sq, D) float — Sq=1 decode or Sq=C chunk
     k_pool: jax.Array,  # (n_pages, page_size, KV, D) float — the page pool
     v_pool: jax.Array,  # (n_pages, page_size, KV, D) float
     kv_len: jax.Array,              # (B,) int32 per-slot fill levels
     block_table: jax.Array,         # (B, max_pages) int32; 0 = trash page
-    mask: Optional[jax.Array] = None,  # (B, 1, max_pages*page_size) bool
+    mask: Optional[jax.Array] = None,  # (B, Sq, max_pages*page_size) bool
     softmax_mode: str = "pot",
     fold_scale: bool = False,
     block_k: int | None = None,
@@ -357,14 +357,15 @@ def raceit_attention_decode_gqa_paged(
     each logical key tile to its physical page. Bit-identical to
     `raceit_attention_decode_paged` on the same pool (repeat commutes with
     everything after quantization) and hence to the contiguous wrappers on
-    the gathered layout. Decode-only (Sq=1): chunk calls take the flat
-    paged entry, whose row dim is free for chunk positions.
+    the gathered layout. A chunk call (Sq > 1) puts each group's rep x Sq
+    (head, position) queries on the tile's rows, with ``mask`` carrying
+    each position's causal rule to its heads' rows: rows are independent
+    in the kernel, so this too is bit-identical to the flat entry, and the
+    pool is never repeated to H.
     """
     from .acam_attention import DEFAULT_BLOCK_G, DEFAULT_BLOCK_K
     B, H, Sq, D = q.shape
     n_pages, ps, KV, hd = k_pool.shape
-    if Sq != 1:
-        raise ValueError(f"decode path expects Sq=1, got {Sq}")
     if H % KV:
         raise ValueError(f"n_heads={H} not a multiple of n_kv_heads={KV}")
     rep = H // KV
@@ -375,10 +376,10 @@ def raceit_attention_decode_gqa_paged(
                           for c in (k_codes, v_codes))
     if mask is not None:
         Sk = block_table.shape[1] * ps
-        mask = jnp.broadcast_to(mask[:, None], (B, KV, rep, Sk)) \
-            .reshape(B * KV, rep, Sk)
+        mask = jnp.broadcast_to(mask[:, None, None], (B, KV, rep, Sq, Sk)) \
+            .reshape(B * KV, rep * Sq, Sk)
     out32, cmax = acam_attention_decode_gqa_codes(
-        qq.codes.reshape(B, KV, rep, D).reshape(B * KV, rep, D),
+        qq.codes.reshape(B * KV, rep * Sq, D),
         k_rows, v_rows, qq.scale * k_scale,
         expand_row_lens(kv_len, KV), mask=mask,
         mode=softmax_mode, scale_by_sqrt_d=None if fold_scale else D,

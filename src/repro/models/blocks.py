@@ -42,6 +42,8 @@ def init_layer(key, cfg: ModelConfig, mixer: str, ffn_kind: str, dtype,
     p: Params = {"norm1": layers.init_norm(cfg, dtype)}
     if mixer in ("attn", "attn_local"):
         p["attn"] = layers.init_attention(ks[0], cfg, dtype)
+    elif mixer == "mla":
+        p["mla"] = layers.init_mla(ks[0], cfg, dtype)
     elif mixer == "mamba":
         p["mamba"] = ssm.init_mamba_with_out(ks[0], cfg, dtype)
     if cross:
@@ -67,7 +69,12 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
                 block_table: Optional[jax.Array] = None,
                 page_size: Optional[int] = None,
                 chunk_offs: Optional[jax.Array] = None,
+                live: Optional[jax.Array] = None,
                 ) -> tuple[jax.Array, Any]:
+    """One layer. ``live`` (B, S) marks the rows that carry a token (None:
+    all), the rows an expert layer routes. With a cache, an expert layer
+    that counts its routed rows returns them as the new cache's
+    ``expert_rows`` (`apply_stack` sums them)."""
     plan = as_plan(cfg, plan)
     # per-mixer-kind plan overrides (ExecConfig.layer_overrides): e.g. pin
     # sliding-window "attn_local" layers to the staged path while global
@@ -76,8 +83,10 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
     plan = _mixer_plan(plan, mixer)
     h = layers.apply_norm(p["norm1"], x, cfg)
     if mixer in ("attn", "attn_local"):
-        # layer scopes (attn, mlp; kv_pool inside attention) name the
-        # layer's device work in the HLO metadata a profile reports
+        # layer scopes (attn, mlp; kv_pool inside attention; mla inside
+        # attn and moe inside mlp, so a reader that knows only the outer
+        # names still sees each layer) name the layer's device work in the
+        # HLO metadata a profile reports
         with jax.named_scope("attn"):
             m, new_cache = layers.attention(
                 p["attn"], h, cfg=cfg, plan=plan, positions=positions,
@@ -88,6 +97,15 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
                 page_size=page_size, chunk_offs=chunk_offs)
         if cache is not None:
             new_cache = {"attn": new_cache}
+    elif mixer == "mla":
+        with jax.named_scope("attn"), jax.named_scope("mla"):
+            m, new_cache = layers.mla_attention(
+                p["mla"], h, cfg=cfg, plan=plan, positions=positions,
+                cache=cache.get("mla") if cache else None,
+                slot_lens=slot_lens, block_table=block_table,
+                page_size=page_size, chunk_offs=chunk_offs)
+        if cache is not None:
+            new_cache = {"mla": new_cache}
     elif mixer == "mamba":
         m, new_cache = ssm.mamba(p["mamba"], h, cfg=cfg, plan=plan,
                                  cache=cache.get("mamba") if cache else None)
@@ -110,8 +128,12 @@ def apply_layer(p: Params, x: jax.Array, *, cfg: ModelConfig,
             x = x + layers.ffn(p["ffn"], h2, cfg, plan)
     elif ffn_kind == "moe":
         h2 = layers.apply_norm(p["norm2"], x, cfg)
-        with jax.named_scope("mlp"):
-            x = x + moe_mod.moe(p["moe"], h2, cfg, plan, mesh_ctx)
+        with jax.named_scope("mlp"), jax.named_scope("moe"):
+            y, n_routed = moe_mod.moe(p["moe"], h2, cfg, plan, mesh_ctx,
+                                      live=live)
+        x = x + y
+        if cache is not None and n_routed is not None:
+            new_cache = dict(new_cache or {}, expert_rows=n_routed)
     # sequence-parallel residual stream: the carried activation (and thus the
     # remat stash) lives sharded over "model"; XLA inserts AG/RS at the
     # boundaries that need full sequence (Megatron-SP pattern).
@@ -138,6 +160,16 @@ def init_layer_cache(cfg: ModelConfig, mixer: str, batch: int, max_len: int,
     page 0 is the trash page and is never handed to a slot.
     """
     if page_size is not None:
+        if mixer == "mla":
+            # the latent pool: one row of latent + RoPE key per token,
+            # read by every head as key and value (layers.mla_attention)
+            if n_pages is None:
+                raise ValueError("paged caches need n_pages")
+            return {"mla": {
+                "kv": jnp.zeros((n_pages, page_size, 1, cfg.mla_head_dim),
+                                dtype),
+                "idx": jnp.zeros((batch,), jnp.int32),
+            }}
         if mixer == "attn":
             hd = cfg.resolved_head_dim
             if n_pages is None:
@@ -153,6 +185,10 @@ def init_layer_cache(cfg: ModelConfig, mixer: str, batch: int, max_len: int,
             f"block-paged caches cover global attention layers only; "
             f"mixer {mixer!r} keeps its own state layout (serve contiguous "
             f"for this config)")
+    if mixer == "mla":
+        raise NotImplementedError(
+            "latent attention caches are block-paged only (pass page_size "
+            "and n_pages; serve with the paged ContinuousBatcher)")
     if mixer in ("attn", "attn_local"):
         hd = cfg.resolved_head_dim
         # local layers keep a ring buffer of window size (DESIGN.md §4)
@@ -179,32 +215,56 @@ def init_layer_cache(cfg: ModelConfig, mixer: str, batch: int, max_len: int,
 # --------------------------------------------------------------------------
 
 def layer_plan(cfg: ModelConfig, n_layers: Optional[int] = None):
+    """(period P, scanned repetitions n_full, every layer's (mixer, ffn)).
+
+    The stack runs the ``cfg.first_k_dense`` leading layers unrolled, then
+    scans n_full repetitions of the period, then the unrolled tail."""
     n = n_layers if n_layers is not None else cfg.n_layers
     P = cfg.block_period
-    n_full = n // P
+    n_full = (n - cfg.first_k_dense) // P
     specs = [cfg.layer_spec(i) for i in range(n)]
     return P, n_full, specs
+
+
+def _counts_expert_rows(specs) -> bool:
+    return any(ffn == "moe" for _, ffn in specs)
+
+
+def live_rows(batch: int, sq: int, slot_lens=None, chunk_offs=None):
+    """(B, Sq) bool: the rows of a serving call that carry a token — a
+    chunk call's fed positions, a decode step's occupied slots — or None
+    where every row does."""
+    if slot_lens is None:
+        return None
+    lens = jnp.asarray(slot_lens, jnp.int32)[:, None]
+    if chunk_offs is None:
+        return jnp.broadcast_to(lens > 0, (batch, sq))
+    cols = (jnp.asarray(chunk_offs, jnp.int32)[:, None]
+            + jnp.arange(sq, dtype=jnp.int32)[None, :])
+    return cols < lens
 
 
 def init_stack(key, cfg: ModelConfig, dtype, n_layers: Optional[int] = None,
                cross: bool = False) -> Params:
     P, n_full, specs = layer_plan(cfg, n_layers)
+    lead = cfg.first_k_dense
     keys = jax.random.split(key, len(specs))
     scan_params = []
     for j in range(P):
         if n_full == 0:
             break
-        layer_keys = [keys[r * P + j] for r in range(n_full)]
-        mixer, ffn_kind = specs[j]
+        layer_keys = [keys[lead + r * P + j] for r in range(n_full)]
+        mixer, ffn_kind = specs[lead + j]
         init_j = partial(init_layer, cfg=cfg, mixer=mixer, ffn_kind=ffn_kind,
                          dtype=dtype, cross=cross)
         scan_params.append(jax.vmap(init_j)(jnp.stack(layer_keys)))
-    tail_params = []
-    for i in range(n_full * P, len(specs)):
-        mixer, ffn_kind = specs[i]
-        tail_params.append(init_layer(keys[i], cfg, mixer, ffn_kind, dtype,
-                                      cross=cross))
-    return {"scan": scan_params, "tail": tail_params}
+    unrolled = lambda idx: [init_layer(keys[i], cfg, *specs[i], dtype,
+                                       cross=cross) for i in idx]
+    out = {"scan": scan_params,
+           "tail": unrolled(range(lead + n_full * P, len(specs)))}
+    if lead:
+        out["lead"] = unrolled(range(lead))
+    return out
 
 
 def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
@@ -212,6 +272,7 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
                      page_size: Optional[int] = None,
                      n_pages: Optional[int] = None) -> Params:
     P, n_full, specs = layer_plan(cfg, n_layers)
+    lead = cfg.first_k_dense
 
     def stack_tree(tree, n):
         return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), tree)
@@ -220,17 +281,21 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
     for j in range(P):
         if n_full == 0:
             break
-        mixer, _ = specs[j]
+        mixer, _ = specs[lead + j]
         c = init_layer_cache(cfg, mixer, batch, max_len, dtype,
                              page_size=page_size, n_pages=n_pages)
         scan_caches.append(stack_tree(c, n_full) if c is not None else {})
-    tail_caches = []
-    for i in range(n_full * P, len(specs)):
-        mixer, _ = specs[i]
-        tail_caches.append(init_layer_cache(cfg, mixer, batch, max_len, dtype,
-                                            page_size=page_size,
-                                            n_pages=n_pages) or {})
-    return {"scan": scan_caches, "tail": tail_caches}
+    unrolled = lambda idx: [init_layer_cache(
+        cfg, specs[i][0], batch, max_len, dtype, page_size=page_size,
+        n_pages=n_pages) or {} for i in idx]
+    out = {"scan": scan_caches,
+           "tail": unrolled(range(lead + n_full * P, len(specs)))}
+    if lead:
+        out["lead"] = unrolled(range(lead))
+    if _counts_expert_rows(specs):
+        # the last call's (row, choice) pairs routed to held experts
+        out["expert_rows"] = jnp.zeros((), jnp.int32)
+    return out
 
 
 def _remat_wrap(fn, cfg: ModelConfig):
@@ -274,26 +339,47 @@ def apply_stack(params: Params, x: jax.Array, *, cfg: ModelConfig,
     """
     plan = as_plan(cfg, plan)
     P, n_full, specs = layer_plan(cfg, n_layers)
+    lead = cfg.first_k_dense
     has_cache = caches is not None
+    counting = has_cache and _counts_expert_rows(specs)
+    layer = partial(apply_layer, cfg=cfg, plan=plan, positions=positions,
+                    mesh_ctx=mesh_ctx, enc_kv=None, pad_lens=pad_lens,
+                    pad_prompt_len=pad_prompt_len, slot_lens=slot_lens,
+                    block_table=block_table, page_size=page_size,
+                    chunk_offs=chunk_offs,
+                    live=live_rows(x.shape[0], x.shape[1], slot_lens,
+                                   chunk_offs))
+
+    def unrolled(x, n, group, idx):
+        new = []
+        for t, i in enumerate(idx):
+            mixer, ffn_kind = specs[i]
+            cache_t = caches[group][t] if has_cache else None
+            x, nc = layer(params[group][t], x, mixer=mixer,
+                          ffn_kind=ffn_kind,
+                          cache=(cache_t if cache_t else None))
+            nc = dict(nc or {})
+            n = n + nc.pop("expert_rows", 0)
+            new.append(nc)
+        return x, n, new
+
+    n_routed = jnp.zeros((), jnp.int32)
+    x, n_routed, new_lead = unrolled(x, n_routed, "lead", range(lead))
 
     if n_full > 0:
         def body(carry, xs):
-            x = carry
+            x, n = carry if counting else (carry, 0)
             p_list, c_list = xs
             new_cs = []
             for j in range(P):
-                mixer, ffn_kind = specs[j]
+                mixer, ffn_kind = specs[lead + j]
                 cache_j = c_list[j] if has_cache else None
-                x, nc = apply_layer(
-                    p_list[j], x, cfg=cfg, plan=plan, mixer=mixer,
-                    ffn_kind=ffn_kind, positions=positions,
-                    cache=(cache_j if cache_j else None), mesh_ctx=mesh_ctx,
-                    enc_kv=None, pad_lens=pad_lens,
-                    pad_prompt_len=pad_prompt_len, slot_lens=slot_lens,
-                    block_table=block_table, page_size=page_size,
-                    chunk_offs=chunk_offs)
-                new_cs.append(nc if nc is not None else {})
-            return x, tuple(new_cs)
+                x, nc = layer(p_list[j], x, mixer=mixer, ffn_kind=ffn_kind,
+                              cache=(cache_j if cache_j else None))
+                nc = dict(nc or {})
+                n = n + nc.pop("expert_rows", 0)
+                new_cs.append(nc)
+            return ((x, n) if counting else x), tuple(new_cs)
 
         body_fn = _remat_wrap(body, cfg) if use_remat else body
         scan_caches = tuple(caches["scan"]) if has_cache else tuple(
@@ -301,24 +387,22 @@ def apply_stack(params: Params, x: jax.Array, *, cfg: ModelConfig,
         # what the scan adds itself (slicing each layer's weights and
         # cache in, stacking the updated cache out) reports as layer_scan
         with jax.named_scope("layer_scan"):
-            x, new_scan = jax.lax.scan(
-                body_fn, x, (tuple(params["scan"]), scan_caches),
+            carry, new_scan = jax.lax.scan(
+                body_fn, (x, n_routed) if counting else x,
+                (tuple(params["scan"]), scan_caches),
                 unroll=cfg.scan_unroll)
+        x, n_routed = carry if counting else (carry, n_routed)
     else:
         new_scan = ()
 
-    new_tail = []
-    for t, i in enumerate(range(n_full * P, len(specs))):
-        mixer, ffn_kind = specs[i]
-        cache_t = caches["tail"][t] if has_cache else None
-        x, nc = apply_layer(
-            params["tail"][t], x, cfg=cfg, plan=plan, mixer=mixer,
-            ffn_kind=ffn_kind, positions=positions,
-            cache=(cache_t if cache_t else None), mesh_ctx=mesh_ctx,
-            enc_kv=None, pad_lens=pad_lens, pad_prompt_len=pad_prompt_len,
-            slot_lens=slot_lens, block_table=block_table,
-            page_size=page_size, chunk_offs=chunk_offs)
-        new_tail.append(nc if nc is not None else {})
+    x, n_routed, new_tail = unrolled(x, n_routed, "tail",
+                                     range(lead + n_full * P, len(specs)))
 
-    new_caches = ({"scan": list(new_scan), "tail": new_tail} if has_cache else None)
+    if not has_cache:
+        return x, None
+    new_caches = {"scan": list(new_scan), "tail": new_tail}
+    if lead:
+        new_caches["lead"] = new_lead
+    if counting:
+        new_caches["expert_rows"] = n_routed
     return x, new_caches

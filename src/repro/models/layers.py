@@ -54,6 +54,14 @@ class QuantizedWeight:
         return cls(children[0], children[1], aux[0])
 
 
+def dequantize(w):
+    """A weight as floats: resident codes times their scales, reshaped."""
+    if not isinstance(w, QuantizedWeight):
+        return w
+    return (w.codes.astype(jnp.float32) * w.scale).reshape(
+        *w.codes.shape[:-1], *w.shape)
+
+
 def _probs_dtype(cfg: ModelConfig):
     """dtype of the p matrix fed to the PV matmul (perf knob; f32 compute
     keeps both paths bit-consistent)."""
@@ -85,14 +93,19 @@ def init_norm(cfg: ModelConfig, dtype) -> Params:
     return {}  # np_layernorm: non-parametric (olmo)
 
 
-def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
-        return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, p["scale"], cfg.norm_eps)
+    xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + 1e-6)
+    y = (xf - mu) * jax.lax.rsqrt(var + cfg.norm_eps)
     if cfg.norm == "layernorm":
         y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     return y.astype(x.dtype)
@@ -151,6 +164,20 @@ def _linear(x: jax.Array, w: jax.Array, plan: ExecPlan,
     activations with the plan's ``act_bits``.
     """
     return plan.matmul(x, w, bias=bias)
+
+
+def _per_head_linear(x: jax.Array, w, plan: ExecPlan) -> jax.Array:
+    """x (B, S, H, K) @ w (H, K, N), head by head: each head's matrix is
+    its own crossbar (`_linear` per head, activations quantized per head).
+    ``w`` may be a head-stacked `QuantizedWeight` (codes (H, K, N))."""
+    return jax.vmap(lambda xh, wh: _linear(xh, wh, plan),
+                    in_axes=(2, 0), out_axes=2)(x, w)
+
+
+def _out_proj(w):
+    """An output projection (heads, head_dim, D) as the (heads*head_dim, D)
+    matrix `_linear` contracts; resident codes are already flat."""
+    return w if isinstance(w, QuantizedWeight) else w.reshape(-1, w.shape[-1])
 
 
 # --------------------------------------------------------------------------
@@ -432,7 +459,7 @@ def _raceit_paged_decode(q, k_pool, v_pool, kv_len, scale, plan: ExecPlan,
     mask = pad_valid
     if mask is not None and mask.ndim == 2:  # (B, Smax) -> (B, Sq, Smax)
         mask = mask[:, None, :]
-    fn = (raceit_attention_decode_gqa_paged if gqa and sq == 1
+    fn = (raceit_attention_decode_gqa_paged if gqa
           else raceit_attention_decode_paged)
     out = fn(qh, k_pool.astype(jnp.float32), v_pool.astype(jnp.float32),
              kv_len, block_table, mask=mask,
@@ -687,38 +714,19 @@ def attention(
             raise ValueError("paged slots are never left-padded; pad_lens "
                              "does not apply")
 
+    scale = 1.0 / math.sqrt(hd)
+    if paged:
+        o, new_cache = _paged_attend(
+            q, {"k": k, "v": v}, cache, plan=plan, scale=scale,
+            slot_lens=slot_lens, block_table=block_table,
+            page_size=page_size, chunk_offs=chunk_offs)
+        return _attention_out(p, o, x, cfg, plan), new_cache
+
     new_cache = None
     # the cache writes (and, inside the paged decode wrappers, the
     # pool's quantization) report under kv_pool
     with jax.named_scope("kv_pool"):
-        if paged:
-            ps = int(page_size)
-            lens = jnp.asarray(slot_lens, jnp.int32)
-            bt = jnp.asarray(block_table, jnp.int32)
-            rows = jnp.arange(b)
-            if chunk_offs is not None:
-                # chunked prefill: fenced physical targets from the shared
-                # routing helper (trash page 0 for dead or overflow columns)
-                pages, slot = paged_write_targets_chunk(bt, lens, chunk_offs,
-                                                        sq, ps)
-                ck = cache["k"].at[pages, slot].set(
-                    k.astype(cache["k"].dtype))
-                cv = cache["v"].at[pages, slot].set(
-                    v.astype(cache["v"].dtype))
-            else:
-                if sq != 1:
-                    raise ValueError(
-                        "paged caches take Sq=1 decode steps or chunked "
-                        "prefill (chunk_offs); whole-prompt prefill goes "
-                        "through Model.prefill_chunk")
-                pages, slot = paged_write_targets_decode(bt, lens, ps)
-                ck = cache["k"].at[pages, slot].set(
-                    k[:, 0].astype(cache["k"].dtype))
-                cv = cache["v"].at[pages, slot].set(
-                    v[:, 0].astype(cache["v"].dtype))
-            new_cache = {"k": ck, "v": cv, "idx": lens}
-            k, v = ck, cv
-        elif cache is not None and cross_kv is None:
+        if cache is not None and cross_kv is None:
             idx = cache["idx"]
             per_slot = getattr(idx, "ndim", 0) == 1  # slot-pool cache
             L = cache["k"].shape[1]
@@ -754,45 +762,7 @@ def attention(
             if sq == 1:  # decode attends through the cache
                 k, v = ck, cv
 
-    scale = 1.0 / math.sqrt(hd)
-
-    if paged:
-        # decode or chunk step against the page pool, lengths from
-        # slot_lens; kv_len is the logical fill, capped at table capacity
-        mp = bt.shape[1]
-        lk = mp * ps
-        kv_len = jnp.minimum(lens, lk)
-        pad_valid = None
-        if chunk_offs is not None:
-            # intra-chunk causality: query j of row b sits at absolute
-            # position chunk_offs[b] + j and attends logical columns <= it
-            qpos = (jnp.asarray(chunk_offs, jnp.int32)[:, None]
-                    + jnp.arange(sq, dtype=jnp.int32)[None, :])
-            pad_valid = (jnp.arange(lk, dtype=jnp.int32)[None, None, :]
-                         <= qpos[..., None])
-        if plan.op("attention_decode").spec.paged:
-            o = plan.attention_decode(q, k, v, kv_len=kv_len, scale=scale,
-                                      pad_valid=pad_valid, block_table=bt,
-                                      page_size=ps)
-        else:
-            # non-paged backend under a paged cache (digital/staged
-            # baselines, explicit contiguous pins): gather the table's
-            # pages back to contiguous rows — a degrade, never an error.
-            # Columns past each row's kv_len are zeroed to reproduce a
-            # contiguous cache's never-written tail exactly: a row's
-            # out-of-range columns gather the shared trash page, whose
-            # content is other rows' fenced garbage — left in place it
-            # would pollute whole-tensor quantizer scales in the staged
-            # raceit paths and, when a faulted row parked NaNs there,
-            # contaminate healthy rows through prob-0 * NaN
-            kvh, hdim = k.shape[2], k.shape[3]
-            live = (jnp.arange(lk, dtype=jnp.int32)[None, :]
-                    < kv_len[:, None])[:, :, None, None]
-            o = plan.attention_decode(
-                q, jnp.where(live, k[bt].reshape(b, lk, kvh, hdim), 0),
-                jnp.where(live, v[bt].reshape(b, lk, kvh, hdim), 0),
-                kv_len=kv_len, scale=scale, pad_valid=pad_valid)
-    elif sq == 1 and cache is not None:
+    if sq == 1 and cache is not None:
         # decode: single query against the cache, masked by validity/window.
         # (ring buffers: every written slot is inside the window by design,
         # so validity is always a prefix of length min(idx, buffer_len))
@@ -836,6 +806,13 @@ def attention(
                                    pad_lens=(pad_lens if cross_kv is None
                                              else None))
 
+    return _attention_out(p, o, x, cfg, plan), new_cache
+
+
+def _attention_out(p: Params, o, x, cfg: ModelConfig, plan: ExecPlan):
+    """The heads' outputs (B, Sq, H, hd) through the output projection."""
+    b, sq = x.shape[:2]
+    hd = cfg.resolved_head_dim
     wq = p["wq"]
     heff = wq.shape[0] if isinstance(wq, QuantizedWeight) else wq.shape[1]
     o = o.reshape(b, sq, heff, hd).astype(x.dtype)
@@ -843,9 +820,169 @@ def attention(
         o = o * (jnp.arange(heff) < cfg.n_heads)[None, None, :, None].astype(o.dtype)
     wo = p["wo"]
     if isinstance(wo, QuantizedWeight):  # codes already (H*hd, D)
-        out = _linear(o.reshape(b, sq, heff * hd), wo, plan)
+        return _linear(o.reshape(b, sq, heff * hd), wo, plan)
+    return jnp.einsum("bshd,hdm->bsm", o, wo.astype(x.dtype))
+
+
+def _paged_attend(q, rows: dict, cache: Params, *, plan: ExecPlan, scale,
+                  slot_lens, block_table, page_size, chunk_offs):
+    """Write this step's cache rows into the page pool(s), then attend the
+    pool: the decode step (Sq=1) or a chunked-prefill call (``chunk_offs``
+    given), lengths from ``slot_lens`` (see `attention`).
+
+    ``rows`` maps each pool of ``cache`` to its new rows (B, Sq, KV, hd):
+    ``{"k": k, "v": v}``, or ``{"kv": row}`` for a latent cache whose one
+    pool serves as keys and values both. Returns (o, new_cache).
+    """
+    b, sq = q.shape[:2]
+    ps = int(page_size)
+    lens = jnp.asarray(slot_lens, jnp.int32)
+    bt = jnp.asarray(block_table, jnp.int32)
+    # the cache writes (and, inside the paged decode wrappers, the
+    # pool's quantization) report under kv_pool
+    with jax.named_scope("kv_pool"):
+        if chunk_offs is not None:
+            # chunked prefill: fenced physical targets from the shared
+            # routing helper (trash page 0 for dead or overflow columns)
+            pages, slot = paged_write_targets_chunk(bt, lens, chunk_offs,
+                                                    sq, ps)
+            new = {n: cache[n].at[pages, slot].set(r.astype(cache[n].dtype))
+                   for n, r in rows.items()}
+        else:
+            if sq != 1:
+                raise ValueError(
+                    "paged caches take Sq=1 decode steps or chunked "
+                    "prefill (chunk_offs); whole-prompt prefill goes "
+                    "through Model.prefill_chunk")
+            pages, slot = paged_write_targets_decode(bt, lens, ps)
+            new = {n: cache[n].at[pages, slot].set(
+                r[:, 0].astype(cache[n].dtype)) for n, r in rows.items()}
+    new_cache = dict(new, idx=lens)
+    k = new["k"] if "k" in new else new["kv"]
+    v = new["v"] if "v" in new else k
+
+    # decode or chunk step against the page pool, lengths from
+    # slot_lens; kv_len is the logical fill, capped at table capacity
+    mp = bt.shape[1]
+    lk = mp * ps
+    kv_len = jnp.minimum(lens, lk)
+    pad_valid = None
+    if chunk_offs is not None:
+        # intra-chunk causality: query j of row b sits at absolute
+        # position chunk_offs[b] + j and attends logical columns <= it
+        qpos = (jnp.asarray(chunk_offs, jnp.int32)[:, None]
+                + jnp.arange(sq, dtype=jnp.int32)[None, :])
+        pad_valid = (jnp.arange(lk, dtype=jnp.int32)[None, None, :]
+                     <= qpos[..., None])
+    if plan.op("attention_decode").spec.paged:
+        o = plan.attention_decode(q, k, v, kv_len=kv_len, scale=scale,
+                                  pad_valid=pad_valid, block_table=bt,
+                                  page_size=ps)
     else:
-        out = jnp.einsum("bshd,hdm->bsm", o, wo.astype(x.dtype))
+        # non-paged backend under a paged cache (digital/staged
+        # baselines, explicit contiguous pins): gather the table's
+        # pages back to contiguous rows — a degrade, never an error.
+        # Columns past each row's kv_len are zeroed to reproduce a
+        # contiguous cache's never-written tail exactly: a row's
+        # out-of-range columns gather the shared trash page, whose
+        # content is other rows' fenced garbage — left in place it
+        # would pollute whole-tensor quantizer scales in the staged
+        # raceit paths and, when a faulted row parked NaNs there,
+        # contaminate healthy rows through prob-0 * NaN
+        kvh, hdim = k.shape[2], k.shape[3]
+        live = (jnp.arange(lk, dtype=jnp.int32)[None, :]
+                < kv_len[:, None])[:, :, None, None]
+        gather = lambda pool: jnp.where(
+            live, pool[bt].reshape(b, lk, kvh, hdim), 0)
+        kg = gather(k)
+        o = plan.attention_decode(q, kg, kg if v is k else gather(v),
+                                  kv_len=kv_len, scale=scale,
+                                  pad_valid=pad_valid)
+    return o, new_cache
+
+
+# --------------------------------------------------------------------------
+# latent attention (MLA, deepseek-v2/v3)
+# --------------------------------------------------------------------------
+
+MLA_LATENT_NORM_EPS = 1e-6  # deepseek-v3's kv_a_layernorm keeps the default
+
+
+def init_mla(key, cfg: ModelConfig, dtype) -> Params:
+    """Latent attention without a query low-rank path: ``wq`` (d -> H x
+    (nope + rope)), ``wkv_a`` (d -> latent + rope key), an RMSNorm on the
+    latent, the per-head up-projections ``w_uk`` (nope -> latent, read as
+    keys) and ``w_uv`` (latent -> v), and ``wo`` (H x v -> d)."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 5)
+    return {
+        "wq": _dense_init(ks[0], (d, H, dn + dr), dtype),
+        "wkv_a": _dense_init(ks[1], (d, r + dr), dtype),
+        "kv_norm": {"scale": jnp.ones((r,), dtype)},
+        "w_uk": _dense_init(ks[2], (H, dn, r), dtype, fan_in=r),
+        "w_uv": _dense_init(ks[3], (H, r, dv), dtype, fan_in=r),
+        "wo": _dense_init(ks[4], (H, dv, d), dtype, fan_in=H * dv),
+    }
+
+
+def mla_attention(p: Params, x: jax.Array, *, cfg: ModelConfig,
+                  plan: ExecPlan | ExecConfig, positions: jax.Array,
+                  cache: Optional[Params] = None,
+                  slot_lens: Optional[jax.Array] = None,
+                  block_table: Optional[jax.Array] = None,
+                  page_size: Optional[int] = None,
+                  chunk_offs: Optional[jax.Array] = None,
+                  ) -> tuple[jax.Array, Optional[Params]]:
+    """Latent attention in its absorbed form.
+
+    A token's cache row is its normalized latent ``c_kv`` (``kv_lora_rank``)
+    followed by its RoPE'd key ``k_pe`` (``qk_rope_head_dim``), one row
+    shared by every head: head h's key is ``[c_kv @ w_uk[h].T, k_pe]`` and
+    its value ``c_kv @ w_uv[h]``. Folding ``w_uk[h]`` into the query
+    (``q_lat = q_nope @ w_uk[h]``) scores every head against the same
+    cache row, so attention is MQA over one (latent + rope)-wide KV head:
+    ``score = [q_lat, q_pe] . [c_kv, k_pe]``, the published
+    ``q_nope . k_nope + q_pe . k_pe``. The weighted sum of rows is taken
+    as values too; its latent part through ``w_uv[h]`` is head h's
+    output (the rope lanes of the sum are dropped, which is exactly
+    ``w_uv`` zero-padded over them). Softmax scale (nope + rope) ** -0.5.
+
+    Every projection, the per-head absorptions included, runs on the
+    plan's matmul slot. ``cache`` is the paged latent pool
+    ``{"kv": (n_pages, page_size, 1, latent + rope), "idx"}`` (decode and
+    chunk calls, as `attention` takes them); without a cache the whole
+    sequence attends causally (`Model.forward`).
+    """
+    plan = as_plan(cfg, plan)
+    b, sq, _ = x.shape
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = _linear(x, p["wq"], plan)                        # (B, S, H, dn+dr)
+    kv = _linear(x, p["wkv_a"], plan)                    # (B, S, r+dr)
+    c_kv = rms_norm(kv[..., :r], p["kv_norm"]["scale"], MLA_LATENT_NORM_EPS)
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg)  # (B, S, 1, dr)
+    q_pe = apply_rope(q[..., dn:], positions, cfg)
+    q_lat = _per_head_linear(q[..., :dn], p["w_uk"], plan)  # (B, S, H, r)
+    q_abs = jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], -1)
+    row = jnp.concatenate([c_kv[:, :, None, :], k_pe.astype(c_kv.dtype)], -1)
+    scale = 1.0 / math.sqrt(dn + cfg.qk_rope_head_dim)
+    new_cache = None
+    if cache is not None:
+        if block_table is None or slot_lens is None or page_size is None:
+            raise NotImplementedError(
+                "the latent cache is block-paged only: pass block_table, "
+                "page_size and slot_lens")
+        o, new_cache = _paged_attend(
+            q_abs, {"kv": row}, cache, plan=plan, scale=scale,
+            slot_lens=slot_lens, block_table=block_table,
+            page_size=page_size, chunk_offs=chunk_offs)
+    else:
+        o = plan.attention_prefill(q_abs, row, row, scale=scale, q_offset=0,
+                                   kind="causal", window=cfg.window,
+                                   chunk=1024, probs_dtype=_probs_dtype(cfg),
+                                   pad_lens=None)
+    o = _per_head_linear(o[..., :r].astype(x.dtype), p["w_uv"], plan)
+    out = _linear(o.reshape(b, sq, -1), _out_proj(p["wo"]), plan)
     return out, new_cache
 
 

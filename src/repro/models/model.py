@@ -30,7 +30,13 @@ Params = dict
 
 # weight leaves that live on crossbars as int8 conductance codes when serving
 _QUANTIZABLE = {"wq", "wk", "wv", "wo", "w1", "w2", "w3", "unembed",
-                "w_z", "w_x", "w_B", "w_C", "w_dt", "out_proj"}
+                "w_z", "w_x", "w_B", "w_C", "w_dt", "out_proj",
+                "wkv_a", "w_uk", "w_uv"}
+# leaves whose leading axis stacks one matrix per head or per held expert,
+# each its own crossbar: latent attention's per-head absorptions, and the
+# expert stacks directly under a "moe" layer
+_PER_HEAD = {"w_uk", "w_uv"}
+_PER_EXPERT = {"w1", "w2", "w3"}
 
 
 def quantize_model_params(params: Params) -> Params:
@@ -39,39 +45,35 @@ def quantize_model_params(params: Params) -> Params:
 
     Stacked scan leaves (R, K, ...) quantize per layer: codes (R, K, N),
     scale (R, 1, N); the scan slices them to exactly what _linear consumes.
+    Per-head and per-expert stacks (G, K, N) quantize per matrix the same
+    way, scale (G, 1, N), under the scan's layer axis where there is one.
     """
-    def q2d(leaf, stacked: bool, name: str):
+    def q2d(leaf, lead: int, name: str):
+        # ``lead`` leading axes (scan layer, head or expert) index separate
+        # matrices; the next is the contraction, the rest the columns
         arr = jnp.asarray(leaf, jnp.float32)
         if name == "wo":  # contraction spans (heads, head_dim)
-            if stacked:
-                arr = arr.reshape(arr.shape[0], -1, arr.shape[-1])
-            else:
-                arr = arr.reshape(-1, arr.shape[-1])
-        if stacked:
-            flat = arr.reshape(arr.shape[0], arr.shape[1], -1)  # (R, K, N)
-            amax = jnp.max(jnp.abs(flat), axis=1, keepdims=True)
-            shape = tuple(arr.shape[2:])
-        else:
-            flat = arr.reshape(arr.shape[0], -1)                # (K, N)
-            amax = jnp.max(jnp.abs(flat), axis=0, keepdims=True)
-            shape = tuple(arr.shape[1:])
+            arr = arr.reshape(*arr.shape[:lead], -1, arr.shape[-1])
+        flat = arr.reshape(*arr.shape[:lead + 1], -1)
+        amax = jnp.max(jnp.abs(flat), axis=lead, keepdims=True)
+        shape = tuple(arr.shape[lead + 1:])
         scale = jnp.maximum(amax, 1e-12) / 127.0
         codes = jnp.clip(jnp.round(flat / scale), -128, 127).astype(jnp.int8)
         return layers.QuantizedWeight(codes, scale.astype(jnp.float32), shape)
 
-    def walk(tree, stacked=False):
+    def walk(tree, stacked=False, in_moe=False):
         if isinstance(tree, dict):
             out = {}
             for name, leaf in tree.items():
+                lead = int(stacked) + int(name in _PER_HEAD
+                                          or (in_moe and name in _PER_EXPERT))
                 if (name in _QUANTIZABLE and hasattr(leaf, "ndim")
-                        and leaf.ndim >= (3 if stacked else 2)):
-                    out[name] = q2d(leaf, stacked, name)
-                elif name == "moe":
-                    out[name] = leaf  # expert einsums keep bf16 (DESIGN §7)
+                        and leaf.ndim >= lead + 2):
+                    out[name] = q2d(leaf, lead, name)
                 elif name == "scan":
                     out[name] = [walk(x, stacked=True) for x in leaf]
                 else:
-                    out[name] = walk(leaf, stacked)
+                    out[name] = walk(leaf, stacked, in_moe=name == "moe")
             return out
         if isinstance(tree, list):
             return [walk(x, stacked) for x in tree]

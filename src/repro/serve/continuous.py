@@ -290,6 +290,10 @@ class ContinuousBatcher:
         self.chunk_fed_tokens = 0   # prompt tokens the chunk calls fed
         self.chunk_slot_tokens = 0  # n_slots x prefill_chunk per chunk call
         self.tokens_out = 0
+        # (row, choice) pairs the calls routed to this device's experts:
+        # all calls, and decode calls alone (expert layers only)
+        self.expert_rows = 0
+        self.decode_expert_rows = 0
 
     # ------------------------------------------------------------ lifecycle
     @staticmethod
@@ -300,15 +304,16 @@ class ContinuousBatcher:
         `repro.exec.registry.BackendSpec`. The *backend* never disqualifies
         a model — non-paged decode backends are served by the gather
         degrade in `repro.models.layers.attention` — only cache layouts
-        with no paged form do (ring buffers, SSM state).
+        with no paged form do (ring buffers, SSM state). Global attention
+        pages its K and V pools, latent attention its one latent pool.
         """
         cfg = engine.cfg
         if cfg.is_encoder_decoder:
             return "encoder-decoder stacks serve bucketed, not slot-pooled"
         mixers = {cfg.layer_spec(i)[0] for i in range(cfg.n_layers)}
-        if mixers != {"attn"}:
-            return (f"mixers {sorted(mixers - {'attn'})} have no paged "
-                    f"cache form (local ring buffers / SSM state)")
+        if len(mixers) != 1 or not mixers <= {"attn", "mla"}:
+            return (f"mixers {sorted(mixers - {'attn', 'mla'})} have no "
+                    f"paged cache form (local ring buffers / SSM state)")
         return None
 
     @property
@@ -620,8 +625,9 @@ class ContinuousBatcher:
             self.chunk_slot_tokens += self.n * C
             if span.is_enabled():
                 span.set_metadata(fed=fed, slot_tokens=self.n * C)
-        bad, sampled = self._sample_rows(logits)
+        bad, sampled, routed = self._sample_rows(logits)
         with _span("batcher.commit") as span:
+            self.expert_rows += routed
             for i in feeding:
                 st = self.slots[i]
                 if bad[i]:
@@ -778,8 +784,10 @@ class ContinuousBatcher:
                     jnp.asarray(pad_lens), jnp.int32(self.prefill_len),
                     jnp.asarray(slot_lens))
             self.decode_steps += 1
-        bad, toks = self._sample_rows(logits)
+        bad, toks, routed = self._sample_rows(logits)
         with _span("batcher.commit") as span:
+            self.expert_rows += routed
+            self.decode_expert_rows += routed
             for i in active:
                 st = self.slots[i]
                 if bad[i]:
@@ -809,15 +817,22 @@ class ContinuousBatcher:
     def _sample_rows(self, logits):
         """Each row's next token from its last-position logits, and the
         rows whose logits are not finite: dispatched in ``batcher.sample``,
-        read back in ``batcher.wait`` (the step's blocking reads)."""
+        read back in ``batcher.wait`` (the step's blocking reads), with
+        the call's count of rows routed to this device's experts
+        (``cache["expert_rows"]``; 0 for a model without expert layers)."""
         eng = self.engine
         with _span("batcher.sample"):
             last = logits[:, -1]
             bad = eng.nonfinite_rows(last)
             self.rng, sub = jax.random.split(self.rng)
             toks = eng._sample(last, sub)
+        routed = (self.cache.get("expert_rows")
+                  if isinstance(self.cache, dict) else None)
         with _span("batcher.wait"):
-            return np.asarray(bad), np.asarray(toks)
+            if routed is None:
+                return np.asarray(bad), np.asarray(toks), 0
+            bad, toks, routed = jax.device_get((bad, toks, routed))
+            return bad, toks, int(routed)
 
     def run_all(self) -> dict[int, Request]:
         while self.queue or any(s is not None for s in self.slots):
@@ -838,6 +853,8 @@ class ContinuousBatcher:
             "decode_tokens": self.decode_tokens,
             "tokens_out": self.tokens_out,
             "model_calls": self.model_calls,
+            "expert_rows": self.expert_rows,
+            "decode_expert_rows": self.decode_expert_rows,
             "fairness_jain": self.metrics.fairness(self.queue.weights),
         }
         if self.mesh_spec is not None:

@@ -194,6 +194,33 @@ def test_paged_chunk_call_matches_masked_contiguous(rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("KV,rep,D,one_pool", [(2, 2, 16, False),
+                                                (1, 4, 36, True)])
+def test_gqa_paged_chunk_call_matches_flat(KV, rep, D, one_pool):
+    """A chunk call (Sq=C) on the GQA paged entry puts each group's rep x C
+    (head, position) queries on its tile's rows: bit-identical to the flat
+    paged entry, which repeats the pool's codes to H. The second case is
+    the latent cache's shape: one KV head read by every query head, keys
+    and values the same pool, a head width off the lane multiple."""
+    B, ps, mp, C = 3, 8, 6, 4
+    H = KV * rep
+    offs, clens = np.array([9, 0, 3]), np.array([4, 4, 1])
+    lens = tuple(int(t) for t in offs + clens)
+    draws = np.random.default_rng(17)
+    _, _, _, lv, pk, pv, bt = _paged_case(
+        draws, rep=rep, lens=lens, B=B, KV=KV, D=D, ps=ps, mp=mp)
+    pv = pk if one_pool else pv
+    q = jnp.asarray(draws.normal(0, 1.5, (B, H, C, D)), jnp.float32)
+    cols = np.arange(ps * mp)[None, None, :]
+    mask = jnp.asarray(
+        cols < (offs[:, None] + np.arange(C)[None, :] + 1)[..., None])
+    got = raceit_attention_decode_gqa_paged(q, pk, pv, lv, bt, mask=mask,
+                                            block_k=ps)
+    want = raceit_attention_decode_paged(q, pk, pv, lv, bt, mask=mask,
+                                         block_k=ps)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_paged_block_table_is_traced_one_compile(rng):
     """One executable serves every block-table assignment and fill pattern
     — the allocator may shuffle pages freely without re-jitting."""

@@ -26,6 +26,7 @@ from repro.models import layers
 GPT2 = get_config("gpt2-large")            # 20 heads x 64, MHA
 STARCODER2 = get_config("starcoder2-15b")  # 48 heads, 4 KV heads, x 128
 OLMO = get_config("olmo-1b")               # d 2048, SwiGLU d_ff 8192
+MOONLIGHT = get_config("moonlight-16b-a3b")  # one 576-wide latent KV head
 PAGE, SLOTS, MAX_PAGES = 64, 4, 2           # the serving launcher defaults
 N_PAGES = 1 + SLOTS * MAX_PAGES
 
@@ -112,6 +113,22 @@ def test_gqa_paged_decode_compiles(one_chip):
              ((SLOTS, H, 1, hd), jnp.float32), _pool(STARCODER2),
              _pool(STARCODER2), ((SLOTS,), jnp.int32),
              ((SLOTS, MAX_PAGES), jnp.int32))
+
+
+@pytest.mark.parametrize("sq", [1, PAGE])
+def test_latent_pool_paged_attention_compiles(one_chip, sq):
+    """Moonlight's latent cache on the GQA paged entry: one 576-wide KV
+    head (padded to 640 lanes in the kernel) that all 16 query heads read
+    as keys and values, for a decode step and a 64-row chunk call (the
+    chunk's heads x positions on the tile's rows)."""
+    H, w = MOONLIGHT.n_heads, MOONLIGHT.mla_head_dim
+    mask = [((SLOTS, sq, MAX_PAGES * PAGE), jnp.bool_)] if sq > 1 else []
+    _compile(lambda q, kv, n, bt, *m: ops.raceit_attention_decode_gqa_paged(
+                 q, kv, kv, n, bt, mask=m[0] if m else None,
+                 interpret=False), one_chip,
+             ((SLOTS, H, sq, w), jnp.float32),
+             ((N_PAGES, PAGE, 1, w), jnp.float32), ((SLOTS,), jnp.int32),
+             ((SLOTS, MAX_PAGES), jnp.int32), *mask)
 
 
 @pytest.fixture
